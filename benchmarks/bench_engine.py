@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.cachedir import enable_compile_cache
 from repro.core.iosim import IncrementalSimulator, simulate
 from repro.core import _iosim_c
 from repro.engine import Engine, make_forward
@@ -229,6 +230,7 @@ def main():
     ap.add_argument("--out", default="BENCH_engine.json",
                     help="where to write the machine-readable results")
     args = ap.parse_args()
+    enable_compile_cache()
 
     rng = np.random.default_rng(0)
     sizes = args.sizes

@@ -56,6 +56,7 @@ import time
 import jax
 import numpy as np
 
+from repro.cachedir import enable_compile_cache
 from repro.engine import Engine, Mesh
 from repro.serving import BucketedPlanSet, PlanStore, SparseServer
 from repro.serving.metrics import percentile
@@ -163,6 +164,7 @@ def main():
                          "the pipeline sweep")
     ap.add_argument("--out", default="BENCH_serving.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh = Mesh.parse(args.mesh) if args.mesh else None
 

@@ -1,23 +1,20 @@
-"""JAX version-compatibility shims.
+"""One import surface for the JAX sharding and Pallas APIs the tree uses.
 
-The codebase targets the current JAX sharding / Pallas APIs, but must run on
-older installs (0.4.x) too.  Everything version-dependent funnels through this
-module so the rest of the tree imports one stable surface:
+The tree targets the installed JAX (0.9).  Call sites import these names
+from here rather than from JAX directly, so a future API move is a change
+to this module only:
 
-  * ``AxisType``            — ``jax.sharding.AxisType`` or an equivalent enum;
-  * ``make_mesh``           — ``jax.make_mesh`` with ``axis_types`` dropped
-                              when unsupported;
-  * ``set_mesh``            — ``jax.set_mesh`` or an emulation via the
-                              ``Mesh`` context manager (old JAX resolves named
-                              axes from the entered mesh context);
-  * ``get_abstract_mesh``   — ``jax.sharding.get_abstract_mesh`` or the
-                              thread-resources physical mesh (empty when no
-                              mesh is active; callers check ``.empty``);
-  * ``tpu_compiler_params`` — ``pltpu.CompilerParams`` (new) /
-                              ``pltpu.TPUCompilerParams`` (old);
-  * ``shard_map``           — ``jax.shard_map`` (new, ``check_vma``) or
-                              ``jax.experimental.shard_map.shard_map`` (old,
-                              ``check_rep``) behind one keyword surface;
+  * ``AxisType``            — ``jax.sharding.AxisType``;
+  * ``make_mesh``           — ``jax.make_mesh`` with ``Auto`` axis types by
+                              default;
+  * ``set_mesh``            — ``jax.set_mesh``;
+  * ``get_abstract_mesh``   — ``jax.sharding.get_abstract_mesh`` (empty when
+                              no mesh is active; callers check ``.empty``);
+  * ``axis_size``           — ``jax.lax.axis_size``;
+  * ``named_shardings``     — ``jax.jit`` sharding trees (raw specs pass
+                              through under a set mesh);
+  * ``tpu_compiler_params`` — ``pltpu.CompilerParams``;
+  * ``shard_map``           — ``jax.shard_map`` with ``check_vma``;
   * ``host_mesh``           — device-count-validated mesh construction used
                               by both the legacy launch meshes and the
                               engine's sharded execution plans.
@@ -25,24 +22,10 @@ module so the rest of the tree imports one stable surface:
 
 from __future__ import annotations
 
-import enum
 from typing import Optional, Sequence
 
 import jax
-
-# True on JAX installs predating the explicit-sharding API family
-# (set_mesh / AxisType / get_abstract_mesh).  A few call sites need more than
-# an API spelling change on these versions — e.g. known-bad GSPMD interactions
-# are gated off.
-LEGACY_JAX = not hasattr(jax, "set_mesh")
-
-try:  # jax >= 0.7
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - depends on installed jax
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
+from jax.sharding import AxisType
 
 
 def make_mesh(
@@ -51,88 +34,38 @@ def make_mesh(
     axis_types: Optional[Sequence] = None,
     **kwargs,
 ):
-    """``jax.make_mesh`` tolerant of installs without ``axis_types``."""
+    """``jax.make_mesh`` with ``Auto`` axis types unless told otherwise."""
     if axis_types is None:
         axis_types = (AxisType.Auto,) * len(tuple(axis_names))
-    try:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=tuple(axis_types), **kwargs)
-    except TypeError:
-        return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-_entered_mesh = None  # the mesh context we are emulating set_mesh with
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=tuple(axis_types), **kwargs)
 
 
 def set_mesh(mesh) -> None:
-    """``jax.set_mesh`` or an emulation on old JAX.
-
-    Old JAX has no process-global mesh; entering the ``Mesh`` context manager
-    (and leaving any previously entered one) gives the same named-axis
-    resolution for everything traced afterwards.
-    """
-    global _entered_mesh
-    if hasattr(jax, "set_mesh"):
-        jax.set_mesh(mesh)
-        return
-    if _entered_mesh is not None:
-        _entered_mesh.__exit__(None, None, None)
-        _entered_mesh = None
-    if mesh is not None:
-        mesh.__enter__()
-        _entered_mesh = mesh
+    """Set (or, with ``None``, clear) the process-global mesh."""
+    jax.set_mesh(mesh)
 
 
 def get_abstract_mesh():
-    """The active mesh, or an *empty* mesh object when none is set.
-
-    Returns ``jax.sharding.get_abstract_mesh()`` on new JAX; on old JAX the
-    physical mesh of the active ``with mesh:`` context (which ``set_mesh``
-    above enters).  Either way the result supports ``.empty``,
-    ``.axis_names`` and ``.shape``.
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        return getter()
-    from jax.interpreters import pxla
-
-    return pxla.thread_resources.env.physical_mesh
+    """The active mesh, or an *empty* mesh object when none is set; either
+    way the result supports ``.empty``, ``.axis_names`` and ``.shape``."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def axis_size(axis_name):
-    """``jax.lax.axis_size`` with a fallback for JAX versions without it."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    from jax._src import core
-
-    return core.trace_ctx.axis_env.axis_size(axis_name)
+    """Size of a named mesh axis inside a ``shard_map`` body."""
+    return jax.lax.axis_size(axis_name)
 
 
 def named_shardings(mesh, spec_tree):
-    """Convert a pytree of ``PartitionSpec``/``None`` into ``NamedSharding``s.
+    """The ``in_shardings``/``out_shardings`` tree for ``jax.jit``.
 
-    New JAX accepts raw specs (and ``None``) in ``jax.jit``'s
-    ``in_shardings``/``out_shardings`` under a set mesh, so the tree passes
-    through untouched there — in particular ``None`` keeps meaning
-    "unconstrained, compiler's choice".  Old JAX requires ``Sharding``
-    instances; there ``None`` becomes fully replicated (the closest legal
-    spelling).
+    Under a set mesh ``jax.jit`` accepts raw ``PartitionSpec``s (and
+    ``None``, meaning "unconstrained, compiler's choice"), so the tree passes
+    through untouched.
     """
-    if not LEGACY_JAX:
-        return spec_tree
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    def conv(s):
-        if s is None:
-            return NamedSharding(mesh, PartitionSpec())
-        if isinstance(s, PartitionSpec):
-            return NamedSharding(mesh, s)
-        return s
-
-    return jax.tree.map(
-        conv, spec_tree,
-        is_leaf=lambda x: x is None or isinstance(x, PartitionSpec),
-    )
+    del mesh
+    return spec_tree
 
 
 def host_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
@@ -158,30 +91,14 @@ def host_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
 
 
 def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-    """``shard_map`` across the entry-point move and the kwarg rename.
-
-    New JAX exposes ``jax.shard_map`` with ``check_vma``; old JAX has
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``.  ``check``
-    maps onto whichever spelling the install understands (callers here
-    always use explicit collectives, so the default is off).
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn  # type: ignore
-    for kw in ({"check_vma": check}, {"check_rep": check}, {}):
-        try:
-            return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-        except TypeError:
-            continue
-    raise RuntimeError("no usable shard_map entry point in this JAX install")
+    """``jax.shard_map``; ``check`` maps onto ``check_vma`` (callers here
+    always use explicit collectives, so the default is off)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check)
 
 
 def tpu_compiler_params(**kwargs):
-    """Build Pallas TPU compiler params across the class rename."""
+    """Pallas TPU compiler params (``pltpu.CompilerParams``)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)

@@ -1,7 +1,8 @@
 """Optional C accelerator for the Algorithm-1 I/O simulator and CR moves.
 
-Compiled on first use with the system C compiler into a cache dir and loaded
-via ctypes.  ``repro.core.iosim.simulate`` and ``repro.core.reorder`` use it
+Compiled on first use with the system C compiler into a fixed cache dir
+(``REPRO_CACHE``, else ``<repo>/.repro_cache``) and loaded via ctypes.
+``repro.core.iosim.simulate`` and ``repro.core.reorder`` use it
 transparently when available; the pure-Python implementations remain the
 reference oracles (cross-checked in tests/test_iosim.py).
 
@@ -22,10 +23,11 @@ import ctypes
 import hashlib
 import os
 import subprocess
-import tempfile
 from typing import Optional
 
 import numpy as np
+
+from repro.cachedir import REPO_ROOT
 
 _SRC = r"""
 #include <stdint.h>
@@ -282,7 +284,8 @@ _POLICY_ID = {"min": 0, "lru": 1, "rr": 2}
 
 
 def _cache_dir() -> str:
-    d = os.environ.get("REPRO_CACHE", os.path.join(tempfile.gettempdir(), "repro_cache"))
+    # ``REPRO_CACHE`` when set, else a fixed directory in the checkout
+    d = os.environ.get("REPRO_CACHE") or str(REPO_ROOT / ".repro_cache")
     os.makedirs(d, exist_ok=True)
     return d
 

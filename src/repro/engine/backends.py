@@ -541,13 +541,19 @@ def make_sharded_forward(
     stride = 5 if quant else 4
     arrs = []
     for seg in segments:
-        arrs.extend([jnp.asarray(seg.rows), jnp.asarray(seg.cols),
-                     jnp.asarray(seg.blocks), jnp.asarray(seg.bias)])
+        arrs.extend([seg.rows, seg.cols, seg.blocks, seg.bias])
         if quant:
-            arrs.append(jnp.asarray(seg.scales))
+            arrs.append(seg.scales)
 
-    if jax_mesh is not None:
-        from jax.sharding import PartitionSpec as P
+    if jax_mesh is None:
+        arrs = [jnp.asarray(a) for a in arrs]
+    else:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        # place each shard's slice on its own device once, here, instead of
+        # shipping the whole stack from one device on every call
+        per_shard = NamedSharding(jax_mesh, P("model"))
+        arrs = [jax.device_put(a, per_shard) for a in arrs]
 
         def device_fn(x, valid, *flat):
             h = x

@@ -61,7 +61,7 @@ def _kernel(
     # the narrow dtype; only the VMEM-resident copy is widened
     w = w_ref[0]
     if quant:
-        w = w.astype(jnp.float32) * s_ref[0, 0]
+        w = w.astype(jnp.float32) * s_ref[g]
     acc_ref[...] += jnp.dot(
         x_ref[...], w, preferred_element_type=jnp.float32
     )
@@ -108,11 +108,10 @@ def bsr_matmul(
         pl.BlockSpec((1, bn), lambda g, rows, cols, first, last: (0, cols[g])),
     ]
     if quant:
-        # per-block dequant scale of step g: a (1, 1) SMEM scalar
-        in_specs.append(pl.BlockSpec(
-            (1, 1), lambda g, rows, cols, first, last: (g, 0),
-            memory_space=pltpu.SMEM,
-        ))
+        # per-block dequant scales: the whole [nnz] f32 vector SMEM-resident
+        # (a (1, 1) block of an [nnz, 1] array breaks the TPU (8, 128)
+        # block-shape rule); step g reads ``s_ref[g]``
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(nnz,),
@@ -133,7 +132,7 @@ def bsr_matmul(
     )
     args = (rows, cols, first, last, x, blocks, bias.reshape(1, -1))
     if quant:
-        args += (scales.reshape(-1, 1),)
+        args += (scales,)
     return fn(*args)
 
 
@@ -180,10 +179,11 @@ def _megakernel(
     occupancy.
 
     With ``quant=True`` the streamed ``w_ref`` block is stored in a narrow
-    dtype (bf16/fp8) and an extra ``s_ref`` input carries its per-block f32
-    scale as a (1, 1) SMEM scalar; dequant (``astype(f32) * scale``) is
-    fused right before the dot, so only the VMEM-resident copy is ever
-    widened — HBM traffic stays at the narrow width."""
+    dtype (bf16/fp8) and an extra ``s_ref`` input carries every block's f32
+    scale as one SMEM-resident vector, read at ``s_ref[g]``; dequant
+    (``astype(f32) * scale``) is fused right before the dot, so only the
+    VMEM-resident copy is ever widened — HBM traffic stays at the narrow
+    width."""
     if gate and quant:
         (occ0_ref, x_ref, w_ref, b_ref, s_ref, o_ref, occ_ref,
          acc_ref, h0_ref, h1_ref) = rest
@@ -199,7 +199,7 @@ def _megakernel(
     r = rows_ref[g]
     w = w_ref[0]
     if quant:
-        w = w.astype(jnp.float32) * s_ref[0, 0]
+        w = w.astype(jnp.float32) * s_ref[g]
 
     @pl.when(first_ref[g] == 1)
     def _init():
@@ -242,7 +242,7 @@ def _megakernel(
 
     @pl.when((last_ref[g] == 1) & is_final)
     def _emit():
-        y = acc_ref[...] + b_ref[...].astype(jnp.float32)
+        y = acc_ref[...] + b_ref[0].astype(jnp.float32)
         if final_activation is not None:
             y = final_activation(y)
         o_ref[...] = y.astype(o_ref.dtype)
@@ -251,7 +251,7 @@ def _megakernel(
         c = cols_ref[g]
 
         def _stash(h_ref):
-            y = acc_ref[...] + b_ref[...].astype(jnp.float32)
+            y = acc_ref[...] + b_ref[0].astype(jnp.float32)
             if activation is not None:
                 y = activation(y)
             h_ref[c] = y
@@ -329,14 +329,15 @@ def bsr_megakernel(
         # weight block of step g: streamed, double-buffered by the
         # Pallas pipeline (gated no-op steps still advance it)
         pl.BlockSpec((1, bs, bs), lambda g, *s: (g, 0, 0)),
-        # bias tile of the current output tile (any layer)
-        pl.BlockSpec((1, bs), lambda g, *s: (s[7][g], 0)),
+        # bias tile of the current output tile (any layer), laid out
+        # [T, 1, bs] so the block's last two dims equal the array's (a
+        # (1, bs) block of [T, bs] breaks the TPU (8, 128) block-shape rule)
+        pl.BlockSpec((1, 1, bs), lambda g, *s: (s[7][g], 0, 0)),
     ]
     if quant:
-        # per-block dequant scale of step g: a (1, 1) SMEM scalar riding
-        # the same pipeline as the narrow weight block it rescales
-        in_specs.append(pl.BlockSpec((1, 1), lambda g, *s: (g, 0),
-                                     memory_space=pltpu.SMEM))
+        # per-block dequant scales: the whole [nnz] f32 vector SMEM-resident
+        # across the grid; step g reads ``s_ref[g]``
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
 
     # index maps take (g, *scalar_prefetch); variadic so the same lambdas
     # serve both the 8-array and the gated 9-array prefetch layout
@@ -382,7 +383,7 @@ def bsr_megakernel(
                 bias_idx)
     if gate:
         prefetch += (occ0,)
-    args = (x, blocks, bias_tiles)
+    args = (x, blocks, bias_tiles.reshape(bias_tiles.shape[0], 1, bs))
     if quant:
-        args += (scales.reshape(-1, 1),)
+        args += (scales,)
     return fn(*prefetch, *args)

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 from typing import Callable, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -27,9 +28,13 @@ def bsr_matmul_ref(
     grid_out: int,
     activation: Optional[Callable] = None,
 ) -> jnp.ndarray:
-    """Oracle: y = act(x @ dense(W) + b), accumulated in float32."""
+    """Oracle: y = act(x @ dense(W) + b), accumulated in float32.
+
+    ``Precision.HIGHEST`` keeps the dot in full float32 on every backend: a
+    TPU runs a default-precision f32 dot through bf16 passes."""
     w = bsr_to_dense(rows, cols, blocks, grid_in, grid_out)
-    y = jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32))
+    y = jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
     y = y + bias.astype(jnp.float32)
     if activation is not None:
         y = activation(y)

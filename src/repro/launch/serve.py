@@ -47,6 +47,7 @@ exit, including graceful SIGTERM drain:
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 from collections import deque
 
@@ -54,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.cachedir import enable_compile_cache
 from repro.compat import set_mesh
 from repro.configs import ARCH_IDS, get_config, reduced
 from repro.launch.mesh import make_test_mesh
@@ -132,7 +134,7 @@ def _drive_http(front, args, sizes, names, rng, stop) -> dict:
     return dict(counts)
 
 
-def serve_sparse_ffnn(args) -> None:
+def serve_sparse_ffnn(args) -> int:
     """Serve the paper's sparse-FFNN workload through the serving runtime.
 
     The offline cost (block DAG, Theorem-1 order, CR, lowering) is paid once
@@ -144,6 +146,9 @@ def serve_sparse_ffnn(args) -> None:
     loop.  ``--models K`` serves K differently-pruned variants through one
     ``ModelRouter``/scheduler.  SIGTERM (and SIGINT) trigger a graceful
     drain: queued requests are served, then the process exits.
+
+    Returns the process exit code: 1 when any request came back without a
+    result or any batch failed, else 0.
     """
     import signal
 
@@ -326,13 +331,14 @@ def serve_sparse_ffnn(args) -> None:
         collected = (http_codes.get(200, 0) if front is not None else
                      sum(router.result(name, rid) is not None
                          for name, rid in rids))
-        served = router.metrics_snapshot()["total"]["served"]
-        print(f"served {served} requests across {args.models} models "
-              f"({collected} collected)")
+        totals = router.metrics_snapshot()["total"]
+        print(f"served {totals['served']} requests across {args.models} "
+              f"models ({collected} collected)")
         print(router.summary())
     else:
         collected = (http_codes.get(200, 0) if front is not None else
                      sum(server.result(rid) is not None for _, rid in rids))
+        totals = server.metrics.snapshot()
         print(f"served {server.metrics.served} sparse-FFNN requests "
               f"({collected} collected) — {server.metrics.summary()}")
         if want_breaker or retry is not None:
@@ -372,8 +378,21 @@ def serve_sparse_ffnn(args) -> None:
         print(f"trace: {tracer.recorded} spans recorded "
               f"({tracer.dropped} dropped) -> {path}")
 
+    # a request that came back None failed (evicted results were served);
+    # over HTTP, any final status but 200 (429 is retried) is a failure
+    if front is not None:
+        lost = sum(n for code, n in http_codes.items() if code != 200)
+    else:
+        lost = len(rids) - collected - totals["results_evicted"]
+    if lost or totals["batch_failures"]:
+        print(f"FAILED: {lost} request(s) came back without a result, "
+              f"{totals['batch_failures']} batch failure(s)",
+              file=sys.stderr)
+        return 1
+    return 0
 
-def main():
+
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="mamba2-1.3b")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -474,10 +493,10 @@ def main():
                          "a hung attempt is abandoned and counted (and "
                          "retried under --retries)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.sparse_ffnn:
-        serve_sparse_ffnn(args)
-        return
+        return serve_sparse_ffnn(args)
 
     cfg = reduced(get_config(args.arch)) if args.reduced else get_config(args.arch)
     mesh = make_test_mesh(1, 1)
@@ -527,7 +546,8 @@ def main():
           f"{tokens_out} tokens in {dt:.2f}s "
           f"({tokens_out/max(dt,1e-9):.1f} tok/s greedy)")
     print("sample:", done[0][:16].tolist() if done else "none")
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import LEGACY_JAX, axis_size, get_abstract_mesh
+from repro.compat import axis_size, get_abstract_mesh
 
 from .common import ACTIVATIONS, apply_rope, dense_init, rms_norm, split_keys
 from .config import ModelConfig
@@ -419,11 +419,7 @@ def moe_dense(params: Dict, x: jnp.ndarray, cfg: ModelConfig):
 
     xg = jnp.zeros((E * C + 1, d), x.dtype).at[slot].set(xf[tok])
     yg = _expert_ffn(params, xg[:-1].reshape(E, C, d), cfg)
-    if not LEGACY_JAX:
-        # on old XLA this constraint makes GSPMD miscompile the surrounding
-        # sort/scatter dispatch on multi-axis meshes (wrong values, no error);
-        # it is only a partitioning hint, so drop it there
-        yg = shard(yg, tp(), None, None)
+    yg = shard(yg, tp(), None, None)
     y_sorted = jnp.concatenate([yg.reshape(E * C, d),
                                 jnp.zeros((1, d), yg.dtype)])[slot]
     gsel = gates.reshape(-1)[sidx]

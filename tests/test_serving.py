@@ -349,3 +349,34 @@ def test_metrics_snapshot_shape():
     assert s["bucket_hist"] == {"4": 1}
     assert s["throughput_rps"] == pytest.approx(2.0)
     assert "p50" in m.summary() or "latency" in m.summary()
+
+
+# --------------------------------------------------------------------------- #
+# the serving CLI's exit code
+# --------------------------------------------------------------------------- #
+
+def _serve_cli(monkeypatch, *extra):
+    from repro.launch import serve
+
+    # the CLI turns on the persistent compile cache; not in a test process
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr("sys.argv", [
+        "serve", "--sparse-ffnn", "--backend", "jnp", "--requests", "6",
+        "--batch", "4", "--reorder-iters", "10", "--block", "32",
+        "--ffnn-sizes", "64", "128", "64", *extra])
+    return serve.main()
+
+
+@pytest.mark.parametrize("mode", [(), ("--async",)], ids=["step", "async"])
+def test_serve_cli_exits_zero_when_every_request_is_served(monkeypatch, mode):
+    assert _serve_cli(monkeypatch, *mode) == 0
+
+
+@pytest.mark.parametrize("mode", [(), ("--async",)], ids=["step", "async"])
+def test_serve_cli_exits_nonzero_on_failed_batches(monkeypatch, capsys, mode):
+    def broken(self, x):
+        raise RuntimeError("injected batch failure")
+
+    monkeypatch.setattr(BucketedPlanSet, "__call__", broken)
+    assert _serve_cli(monkeypatch, *mode) == 1
+    assert "FAILED" in capsys.readouterr().err
