@@ -3,7 +3,9 @@
 Three pieces, documented in ``docs/observability.md``:
 
   * :mod:`repro.obs.trace` — :class:`Tracer`, a thread-safe bounded
-    ring-buffer span recorder with Chrome-trace / JSONL export;
+    ring-buffer span recorder with Chrome-trace / JSONL export, whose
+    scoped spans also reach a recording ``jax.profiler`` session and
+    :func:`span_totals`;
   * :mod:`repro.obs.series` — :class:`BoundedSeries`, capped-memory metric
     series with exact-then-bucketed percentiles;
   * :mod:`repro.obs.telemetry` / :mod:`repro.obs.prom` — per-bucket I/O
@@ -12,7 +14,8 @@ Three pieces, documented in ``docs/observability.md``:
 
 from .series import BoundedSeries
 from .telemetry import IOTelemetry, plan_io_attrs
-from .trace import NULL_TRACER, Span, Tracer
+from .trace import (NULL_TRACER, Span, Tracer, reset_span_totals,
+                    span_totals)
 from .prom import MetricsServer, render_prometheus
 
 __all__ = [
@@ -22,6 +25,8 @@ __all__ = [
     "NULL_TRACER",
     "Span",
     "Tracer",
+    "span_totals",
+    "reset_span_totals",
     "MetricsServer",
     "render_prometheus",
 ]

@@ -6,7 +6,8 @@ Design constraints, in order:
 
   * **near-zero overhead when disabled** — every instrumentation site checks
     ``tracer.enabled`` (one attribute read) before building any attribute
-    dict; a disabled tracer records nothing and allocates nothing.
+    dict; a disabled tracer records nothing and allocates nothing while
+    no profiler session records.
     ``NULL_TRACER`` is the shared disabled instance every un-instrumented
     server uses, so the hot path never branches on ``None``;
   * **bounded memory** — spans live in a ``deque(maxlen=capacity)`` ring:
@@ -17,7 +18,14 @@ Design constraints, in order:
     tests produce deterministic traces;
   * **standard export** — :meth:`Tracer.export` writes either Chrome-trace
     JSON (loadable in ``chrome://tracing`` / `Perfetto <https://ui.perfetto.dev>`_)
-    or JSONL (one span object per line, grep/jq-friendly).
+    or JSONL (one span object per line, grep/jq-friendly);
+  * **profiler capture** — while a ``jax.profiler`` session records, every
+    scoped span (``with tracer.span(...)``) of every tracer, the disabled
+    ``NULL_TRACER`` included, is also written into the profiler's trace as
+    a ``TraceAnnotation`` (the ``/host:CPU`` plane, on the device trace's
+    clock), and its duration is added to a process-wide per-name total
+    (:func:`span_totals`).  The session is process-wide, and so are the
+    totals.
 
 Span taxonomy (names, attributes, units) is documented in
 ``docs/observability.md``.
@@ -31,9 +39,44 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
-__all__ = ["Span", "Tracer", "NULL_TRACER"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Span", "Tracer", "NULL_TRACER", "span_totals",
+           "reset_span_totals"]
+
+#: True while a profiler session records host events
+_profiling = TraceAnnotation.is_enabled
+#: the only span attributes written into the profiler's trace
+_PROFILER_ARGS = ("bucket", "rows")
+
+_totals_mu = threading.Lock()
+_totals: Dict[str, List] = {}           # name -> [count, seconds]
+
+
+def _add_total(name: str, seconds: float) -> None:
+    with _totals_mu:
+        t = _totals.get(name)
+        if t is None:
+            _totals[name] = [1, seconds]
+        else:
+            t[0] += 1
+            t[1] += seconds
+
+
+def span_totals() -> Dict[str, Tuple[int, float]]:
+    """``{name: (count, seconds)}`` of the scoped spans that ran while a
+    profiler session was recording (since the last
+    :func:`reset_span_totals`); seconds are wall time on
+    ``time.perf_counter``."""
+    with _totals_mu:
+        return {k: (n, s) for k, (n, s) in _totals.items()}
+
+
+def reset_span_totals() -> None:
+    with _totals_mu:
+        _totals.clear()
 
 
 @dataclasses.dataclass
@@ -99,29 +142,46 @@ _NULL_SPAN = _NullSpan()
 
 
 class _SpanCtx:
-    """Context manager recording one span on exit.  Attributes can be added
-    mid-span with ``sp["key"] = value`` (e.g. an outcome only known at the
-    end of the interval)."""
+    """Context manager timing one span into the sinks that were on when it
+    was made: the tracer's ring (recorded on exit, on the tracer's clock)
+    and the profiler (a ``TraceAnnotation`` carrying at most ``bucket`` and
+    ``rows``, plus the span's :func:`span_totals` entry).  Attributes can
+    be added mid-span with ``sp["key"] = value`` (e.g. an outcome only
+    known at the end of the interval); they go to the ring only."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_t0")
+    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "_ann", "_p0")
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, object]):
-        self._tracer = tracer
+    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, object],
+                 profiling: bool):
+        self._tracer = tracer if tracer.enabled else None
         self._name = name
         self._attrs = attrs
+        self._ann = None
+        if profiling:
+            self._ann = TraceAnnotation(
+                name, **{k: attrs[k] for k in _PROFILER_ARGS if k in attrs})
 
     def __enter__(self) -> "_SpanCtx":
-        self._t0 = self._tracer.clock()
+        if self._ann is not None:
+            self._ann.__enter__()
+            self._p0 = time.perf_counter()
+        if self._tracer is not None:
+            self._t0 = self._tracer.clock()
         return self
 
     def __setitem__(self, key: str, value) -> None:
         self._attrs[key] = value
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        if exc_type is not None:
-            self._attrs.setdefault("error", exc_type.__name__)
-        self._tracer.span_at(self._name, self._t0, self._tracer.clock(),
-                             **self._attrs)
+        tracer = self._tracer
+        if tracer is not None:
+            if exc_type is not None:
+                self._attrs.setdefault("error", exc_type.__name__)
+            tracer.span_at(self._name, self._t0, tracer.clock(),
+                           **self._attrs)
+        if self._ann is not None:
+            _add_total(self._name, time.perf_counter() - self._p0)
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -133,10 +193,12 @@ class Tracer:
         older ones are evicted and counted in ``dropped``.
       clock: monotonic time source (inject the server's fake clock in
         tests; defaults to ``time.monotonic``).
-      enabled: a disabled tracer is inert — ``span``/``event`` return
-        immediately.  Instrumentation sites should additionally guard
-        attribute-dict construction behind ``tracer.enabled`` so a disabled
-        tracer costs one attribute read per site.
+      enabled: a disabled tracer keeps no ring — ``span_at``/``event``
+        return immediately, and ``span`` hands out a shared no-op unless a
+        profiler session is recording.  Instrumentation sites should
+        additionally guard attribute-dict construction behind
+        ``tracer.enabled`` so a disabled tracer costs one attribute read
+        per site.
     """
 
     def __init__(self, capacity: int = 16384,
@@ -156,10 +218,15 @@ class Tracer:
     # recording
     # ------------------------------------------------------------------ #
     def span(self, name: str, **attrs) -> "_SpanCtx | _NullSpan":
-        """Context manager timing one interval: ``with tracer.span("x"): ...``."""
-        if not self.enabled:
+        """Context manager timing one interval: ``with tracer.span("x"): ...``.
+
+        Recorded into the ring when the tracer is enabled, and into the
+        profiler's trace and :func:`span_totals` while a profiler session
+        records; with neither, the shared no-op."""
+        profiling = _profiling()
+        if not (self.enabled or profiling):
             return _NULL_SPAN
-        return _SpanCtx(self, name, attrs)
+        return _SpanCtx(self, name, attrs, profiling)
 
     def span_at(self, name: str, t0: float, t1: float, **attrs) -> None:
         """Record a span whose endpoints were observed elsewhere (e.g. a
@@ -234,5 +301,6 @@ class Tracer:
 
 
 #: Shared disabled tracer: the default for every un-instrumented server, so
-#: hot paths branch on ``tracer.enabled`` instead of ``tracer is None``.
+#: hot paths branch on ``tracer.enabled`` instead of ``tracer is None``.  Its
+#: ring is off; its scoped spans still reach a recording profiler.
 NULL_TRACER = Tracer(capacity=1, enabled=False)

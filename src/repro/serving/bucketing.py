@@ -201,28 +201,41 @@ class BucketedPlanSet:
 
     def __call__(self, x) -> np.ndarray:
         """Run a batch of any size.  ``x`` is ``[n, n_in]``; batches larger
-        than the top bucket are served in top-bucket chunks."""
+        than the top bucket are served in top-bucket chunks, joined at the
+        end (``plans.concat``)."""
         x = np.asarray(x)
         if x.ndim != 2 or x.shape[1] != self.n_in:
             raise ValueError(
                 f"expected input [n, {self.n_in}], got {tuple(x.shape)}")
-        if x.dtype != self.dtype:
-            # cast BEFORE bucket padding: a caller dtype that differs from
-            # the traced one (float64 clients, say) would otherwise lower a
-            # second program per bucket and defeat warmup()
-            x = x.astype(self.dtype)
+        tr = self._tr
+        with tr.span("plans.call", rows=x.shape[0]):
+            if x.shape[0] <= self.max_batch:
+                return self._run_bucket(x)
+            parts = [self._run_bucket(x[i:i + self.max_batch])
+                     for i in range(0, x.shape[0], self.max_batch)]
+            with tr.span("plans.concat", rows=x.shape[0]):
+                return np.concatenate(parts)
+
+    def _run_bucket(self, x: np.ndarray) -> np.ndarray:
+        """One bucket program call for ``n <= max_batch`` rows: cast, pad
+        and dispatch (``plans.dispatch``: the host-to-device copy and the
+        enqueue), then wait for the device and copy back (``plans.fetch``)."""
         n = x.shape[0]
-        if n > self.max_batch:
-            parts = [self(x[i:i + self.max_batch])
-                     for i in range(0, n, self.max_batch)]
-            return np.concatenate(parts)
         b = self.bucket_for(n)
-        if n < b:
-            x = np.concatenate(
-                [x, np.zeros((b - n, x.shape[1]), x.dtype)])
-        self.bucket_calls[b] += 1
-        y = self.plans[b](x)
-        return np.asarray(y)[:n]
+        tr = self._tr
+        with tr.span("plans.dispatch", bucket=b, rows=n):
+            if x.dtype != self.dtype:
+                # cast BEFORE bucket padding: a caller dtype that differs
+                # from the traced one (float64 clients, say) would otherwise
+                # lower a second program per bucket and defeat warmup()
+                x = x.astype(self.dtype)
+            if n < b:
+                x = np.concatenate(
+                    [x, np.zeros((b - n, x.shape[1]), x.dtype)])
+            self.bucket_calls[b] += 1
+            y = self.plans[b](x)
+        with tr.span("plans.fetch", bucket=b, rows=n):
+            return np.asarray(y)[:n]
 
     def describe(self) -> str:
         src = "plan-store hit" if self.cache_hit else "cold compile"

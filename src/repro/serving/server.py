@@ -863,7 +863,7 @@ class SparseServer:
         batch worth of requests, bound to a snapshot of the current plan
         set (and its generation).  Returns None when the policy says wait
         — or, when dispatching, when every eligible lane is full."""
-        with self._lock:
+        with self.tracer.span("batch.form"), self._lock:
             now = self.clock()
             self._evict_expired_requests(now)
             if not self._queue:
@@ -1055,7 +1055,8 @@ class SparseServer:
                     if self._thread is not me:
                         return
                     self._heartbeat.beat()
-                    self._cv.wait(timeout=_IDLE_WAIT_S)
+                    with self.tracer.span("sched.wait"):
+                        self._cv.wait(timeout=_IDLE_WAIT_S)
                 if self._stop.is_set() and \
                         (not self._drain_on_stop or not self._queue):
                     return
@@ -1080,7 +1081,8 @@ class SparseServer:
                     if pipelined or (not self._stop.is_set()
                                      and not self._should_fire_locked()):
                         if not (self._stop.is_set() and not self._queue):
-                            self._cv.wait(timeout=timeout)
+                            with self.tracer.span("sched.wait"):
+                                self._cv.wait(timeout=timeout)
 
     def shutdown(self, drain: bool = True,
                  timeout: Optional[float] = None,
@@ -1281,27 +1283,32 @@ class SparseServer:
             check_finite(y)
         return y
 
-    def _trace_batch(self, reqs: List[Request], plans, bucket: int,
-                     t0: float, t1: float, attempt: int,
-                     error: Optional[BaseException] = None,
-                     worker: Optional[int] = None) -> None:
-        """Record the batch's execute span, each request's retroactive queue
-        span, and per-request done events (tracer enabled — caller checked)."""
-        tr = self.tracer
-        attrs = {"model": self.name, "bucket": bucket, "n": len(reqs),
-                 "attempt": attempt + 1,
-                 "degraded": bool(getattr(plans, "safe_mode", False))}
+    def _execute_attrs(self, sp, plans, bucket: int, n: int, attempt: int,
+                       worker: Optional[int],
+                       error: Optional[BaseException]) -> None:
+        """The ring's attributes of one ``batch.execute`` span (tracer
+        enabled — caller checked)."""
+        sp["model"] = self.name
+        sp["n"] = n
+        sp["attempt"] = attempt + 1
+        sp["degraded"] = bool(getattr(plans, "safe_mode", False))
         if worker is not None:
-            attrs["worker"] = worker
-        attrs.update(plan_io_attrs(plans.plans.get(bucket, plans.base)))
+            sp["worker"] = worker
+        for k, v in plan_io_attrs(plans.plans.get(bucket, plans.base)).items():
+            sp[k] = v
         if error is not None:
-            attrs["error"] = type(error).__name__
-        tr.span_at("batch.execute", t0, t1, **attrs)
+            sp["error"] = type(error).__name__
+
+    def _trace_requests(self, reqs: List[Request], bucket: int, t0: float,
+                        t1: float, ok: bool) -> None:
+        """Each request's retroactive queue span (closing where its batch's
+        last attempt began) and its done event (tracer enabled — caller
+        checked)."""
+        tr = self.tracer
         for r in reqs:
             tr.span_at("request.queue", r.t_submit, t0, model=self.name,
                        rid=r.rid, bucket=bucket)
-            tr.event("request.done", model=self.name, rid=r.rid,
-                     ok=error is None,
+            tr.event("request.done", model=self.name, rid=r.rid, ok=ok,
                      miss=bool(r.deadline is not None and t1 > r.deadline))
 
     def _run_batch(self, batch: FormedBatch,
@@ -1314,48 +1321,54 @@ class SparseServer:
         reqs, plans = batch.reqs, batch.plans
         n = len(reqs)
         bucket = plans.bucket_for(n)
-        x = np.stack([r.x for r in reqs])
-        policy = self.retry
         tr = self.tracer
+        with tr.span("batch.stack", rows=n):
+            x = np.stack([r.x for r in reqs])
+        policy = self.retry
         attempt = 0
         while True:
-            t0 = self.clock()
-            try:
-                y = self._attempt(plans, x)
-                break
-            except Exception as e:
-                # a failed batch must not kill the scheduler thread (in
-                # router mode that would stop EVERY model)
-                timed_out = isinstance(e, BatchTimeoutError)
-                nan_guard = isinstance(e, OutputGuardError)
+            # one span per attempt, over exactly the interval exec_s times
+            with tr.span("batch.execute", bucket=bucket) as sp:
+                t0 = self.clock()
+                try:
+                    y = self._attempt(plans, x)
+                    error = None
+                except Exception as e:
+                    # a failed batch must not kill the scheduler thread (in
+                    # router mode that would stop EVERY model)
+                    error = e
                 t1 = self.clock()
-                if attempt < policy.max_retries:
-                    attempt += 1
-                    with self._lock:
-                        self.metrics.record_retry(timed_out=timed_out,
-                                                  nan_guard=nan_guard)
-                    if tr.enabled:
-                        tr.event("batch.retry", model=self.name,
-                                 bucket=bucket, attempt=attempt,
-                                 error=type(e).__name__)
-                    if policy.backoff_s > 0:
-                        time.sleep(policy.backoff(attempt))
-                    continue
-                # retries exhausted: complete the batch's slots with None
-                # so waiters unblock, count the failure, feed the breaker,
-                # move on
                 if tr.enabled:
-                    self._trace_batch(reqs, plans, bucket, t0, t1,
-                                      attempt, error=e, worker=worker)
-                with self._cv:
-                    self.metrics.record_attempt_failure(timed_out=timed_out,
-                                                        nan_guard=nan_guard)
-                    self._finish_slots(reqs, None, t1)
-                    self.metrics.record_batch_failure(t1, n)
-                    if batch.gen == self._plan_gen:
-                        self._breaker_failure_locked(t1)
-                return n
-        t1 = self.clock()
+                    self._execute_attrs(sp, plans, bucket, n, attempt,
+                                        worker, error)
+            if error is None:
+                break
+            timed_out = isinstance(error, BatchTimeoutError)
+            nan_guard = isinstance(error, OutputGuardError)
+            if attempt < policy.max_retries:
+                attempt += 1
+                with self._lock:
+                    self.metrics.record_retry(timed_out=timed_out,
+                                              nan_guard=nan_guard)
+                if tr.enabled:
+                    tr.event("batch.retry", model=self.name,
+                             bucket=bucket, attempt=attempt,
+                             error=type(error).__name__)
+                if policy.backoff_s > 0:
+                    time.sleep(policy.backoff(attempt))
+                continue
+            # retries exhausted: complete the batch's slots with None so
+            # waiters unblock, count the failure, feed the breaker, move on
+            if tr.enabled:
+                self._trace_requests(reqs, bucket, t0, t1, ok=False)
+            with tr.span("batch.complete", rows=n), self._cv:
+                self.metrics.record_attempt_failure(timed_out=timed_out,
+                                                    nan_guard=nan_guard)
+                self._finish_slots(reqs, None, t1)
+                self.metrics.record_batch_failure(t1, n)
+                if batch.gen == self._plan_gen:
+                    self._breaker_failure_locked(t1)
+            return n
         exec_s = t1 - t0
         # the pipeline wait split: form-wait (submit -> formation) per
         # request, dispatch-wait (formation -> execution start) per batch.
@@ -1366,10 +1379,9 @@ class SparseServer:
         misses = sum(1 for r in reqs
                      if r.deadline is not None and t1 > r.deadline)
         if tr.enabled:
-            self._trace_batch(reqs, plans, bucket, t0, t1, attempt,
-                              worker=worker)
+            self._trace_requests(reqs, bucket, t0, t1, ok=True)
         do_measure = False
-        with self._cv:
+        with tr.span("batch.complete", rows=n), self._cv:
             if self.plans is plans:
                 # don't let a batch that was in flight across a swap() write
                 # the OLD plans' latency into the estimator the swap seeded
@@ -1755,7 +1767,8 @@ class ModelRouter:
                             timeout = min(
                                 timeout, s._seconds_to_fire_locked(now))
                     if not fireable and not self._stop.is_set():
-                        self._cv.wait(timeout=timeout)
+                        with self.tracer.span("sched.wait"):
+                            self._cv.wait(timeout=timeout)
 
     def shutdown(self, drain: bool = True,
                  timeout: Optional[float] = None,

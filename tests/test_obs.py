@@ -16,12 +16,16 @@ Covers the PR-8 acceptance scenarios end to end:
     block-read gauges for a gated model over real HTTP.
 """
 
+import contextlib
+import glob
 import json
 import math
 import threading
 import urllib.error
 import urllib.request
+import warnings
 
+import jax
 import numpy as np
 import pytest
 from conftest import FakeClock
@@ -34,8 +38,10 @@ from repro.obs import (
     Tracer,
     plan_io_attrs,
     render_prometheus,
+    reset_span_totals,
+    span_totals,
 )
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import _NULL_SPAN, NULL_TRACER
 from repro.serving import (
     BucketedPlanSet,
     CircuitBreaker,
@@ -465,6 +471,127 @@ def test_bucket_fanout_and_warmup_traced(make_stack):
     warms = [s for s in spans if s.name == "bucket.warmup"]
     assert sorted(s.attrs["bucket"] for s in warms) == list(plans.buckets)
     assert all(s.attrs["warmup_s"] >= 0.0 for s in warms)
+
+
+# --------------------------------------------------------------------------- #
+# profiler capture: scoped spans on the profiler's clock, and their totals
+# --------------------------------------------------------------------------- #
+
+@contextlib.contextmanager
+def _profiled(tmp_path):
+    """A ``jax.profiler`` session around the block; yields a list that is
+    filled, once the session stops, with the ``/host:CPU`` plane's events
+    as ``(name, start_ns, end_ns, args)``."""
+    reset_span_totals()
+    events = []
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        yield events
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    with warnings.catch_warnings():
+        # the reader's stats type warns when its values are read
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in data.planes:
+            if plane.name == "/host:CPU":
+                events += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                            dict(e.stats))
+                           for line in plane.lines for e in line.events]
+
+
+def _serve_and_chunk(plans, clock, batches=3):
+    """Step-driven serving of ``batches`` batches of 3 rows, then one
+    offline call of three chunks (8 + 8 + 4 rows)."""
+    srv = SparseServer(plans, clock=clock)
+    rng = np.random.default_rng(0)
+    for _ in range(batches):
+        rids = [srv.submit(rng.standard_normal(plans.n_in).astype(np.float32))
+                for _ in range(3)]
+        clock.advance(0.01)
+        srv.drain()
+        assert all(srv.result(r) is not None for r in rids)
+    y = plans(rng.standard_normal((20, plans.n_in)).astype(np.float32))
+    assert y.shape == (20, plans.n_out)
+
+
+def test_profiler_capture_totals_and_host_plane(make_stack, tmp_path):
+    plans = BucketedPlanSet.compile(make_stack(),
+                                    engine=Engine(backend="jnp"), max_batch=8)
+    plans.warmup()
+    with _profiled(tmp_path) as events:
+        _serve_and_chunk(plans, FakeClock())
+    totals = span_totals()
+    # one execute per batch; one dispatch and fetch per bucket call (three
+    # batches, three chunks); one concat, for the chunked call
+    assert totals["batch.execute"][0] == 3
+    assert totals["batch.stack"][0] == totals["batch.complete"][0] == 3
+    assert totals["batch.form"][0] >= 3
+    assert totals["plans.dispatch"][0] == totals["plans.fetch"][0] == 6
+    assert totals["plans.concat"][0] == 1
+    assert totals["plans.call"][0] == 4
+    assert all(s >= 0.0 for _, s in totals.values())
+    # request-following spans and events stay in the ring
+    assert not {"request.queue", "request.submit", "request.done"} & \
+        set(totals)
+
+    names = {e[0] for e in events}
+    assert set(totals) <= names
+    for name, _, _, args in events:
+        if name in totals:
+            assert set(args) <= {"bucket", "rows"}, (name, args)
+    fetches = [e for e in events if e[0] == "plans.fetch"]
+    executes = [e for e in events if e[0] == "batch.execute"]
+    assert len(executes) == 3
+    for _, t0, t1, args in executes:
+        assert any(t0 <= f0 and f1 <= t1 for _, f0, f1, _ in fetches)
+        assert args["bucket"] == 4
+
+
+def test_profiler_capture_async_scheduler(make_stack, tmp_path):
+    plans = BucketedPlanSet.compile(make_stack(),
+                                    engine=Engine(backend="jnp"), max_batch=8)
+    plans.warmup()
+    srv = SparseServer(plans, slo_ms=20.0)
+    with _profiled(tmp_path):
+        srv.start()
+        try:
+            rid = srv.submit(np.zeros(plans.n_in, np.float32))
+            assert srv.wait(rid, timeout=10.0) is not None
+        finally:
+            srv.shutdown()
+    totals = span_totals()
+    assert totals["sched.wait"][0] >= 1
+    assert totals["batch.execute"][0] >= 1
+
+
+def test_ring_and_profiler_share_one_span(tmp_path):
+    clock = FakeClock()
+    tr = Tracer(clock=clock)
+    with _profiled(tmp_path) as events:
+        with tr.span("plans.dispatch", bucket=2, rows=1, model="m") as sp:
+            clock.advance(0.5)
+            sp["k"] = 1
+    (ring,) = tr.spans()
+    assert ring.attrs == {"bucket": 2, "rows": 1, "model": "m", "k": 1}
+    assert ring.dur == pytest.approx(0.5)
+    assert span_totals()["plans.dispatch"][0] == 1
+    (prof,) = [e for e in events if e[0] == "plans.dispatch"]
+    assert prof[3] == {"bucket": 2, "rows": 1}
+
+
+def test_no_session_leaves_spans_null_and_totals_still(make_stack):
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert NULL_TRACER.span("plans.call") is _NULL_SPAN
+    assert Tracer(enabled=False).span("batch.execute", bucket=1) \
+        is _NULL_SPAN
+    plans = BucketedPlanSet.compile(make_stack(),
+                                    engine=Engine(backend="jnp"), max_batch=8)
+    before = span_totals()
+    _serve_and_chunk(plans, FakeClock(), batches=1)
+    assert span_totals() == before
+    assert NULL_TRACER.spans() == []
 
 
 # --------------------------------------------------------------------------- #
