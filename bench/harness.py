@@ -8,7 +8,6 @@ The program is reached through its public entries only: ``repro.engine``,
 from __future__ import annotations
 
 import gc
-import importlib.util
 import json
 import shutil
 import sys
@@ -19,14 +18,14 @@ import types
 from collections import Counter
 from typing import List, Optional
 
-from bench.spec import BENCH_DIR, ROOT, load_cell, read_metrics
+from bench.spec import ROOT, load_cell, load_module, read_metrics
 
 sys.path.insert(0, str(ROOT / "src"))
 
 import jax  # noqa: E402
 from jax import monitoring  # noqa: E402
 
-from bench import counts, loadgen, network, peaks, tracing  # noqa: E402
+from bench import loadgen, network, peaks, tracing  # noqa: E402
 
 LOADS = {"poisson": loadgen.OpenLoop, "closed": loadgen.ClosedLoop,
            "offline": loadgen.Offline}
@@ -57,14 +56,6 @@ class NoChip(RuntimeError):
     """JAX found no accelerator, or fewer chips than the cell asks for."""
 
 
-def _load_reference(name: str):
-    path = BENCH_DIR / "configs" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_ref_{name}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
 def _span_factory(trace: bool):
     return jax.profiler.TraceAnnotation if trace else loadgen.no_span
 
@@ -89,6 +80,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     cell = load_cell(workload)
     config = {**cell.config, **(config_overrides or {})}
     traffic = {**cell.traffic, **(traffic_overrides or {})}
+    ref_mod = load_module(config["reference"], cell.modules)
+    net = network.hooks(config, cell.modules)
 
     devices = jax.devices()
     if require_chip:
@@ -106,7 +99,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     # then load each one
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
-    system = network.build(config, seed)
+    system = network.build(config, seed, net)
     plan_faults = network.plan_problems(system.plans, config)
     load = LOADS[traffic["kind"]](system, config, traffic, seed,
                                       span=_span_factory(trace))
@@ -152,7 +145,6 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         device["busy_s"] = summary.busy_s
         device["window_s"] = summary.window_s
 
-    ref_mod = _load_reference(config["reference"])
     weights, nnz = ref_mod.prune(config, ws)
     xs = pool[window.sample_idx]
     err = None
@@ -175,10 +167,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     run = types.SimpleNamespace(
         config=config, traffic=traffic, window=window, setup_s=setup_s,
         setup_phases=phases, trace=summary, device_kind=device["kind"],
-        counts=counts.SparseFFN(
-            n_in=config["hidden_size"], n_hid=config["intermediate_size"],
-            n_out=config["hidden_size"], block=config["block"], nnz=nnz,
-            weight_dtype=config["weight_dtype"]))
+        counts=net["counts"](config, nnz))
     metrics = read_metrics(cell.per_layer if trace else cell.end_to_end, run)
     if not trace:
         for m in cell.end_to_end:

@@ -41,6 +41,12 @@ NULL_SPAN = contextlib.nullcontext()
 SAMPLE_ROWS = 2048
 CALL_SAMPLE_ROWS = 64        # answers sampled from each offline call
 RESULT_WAIT_S = 60.0
+# A client that waits on its answer keeps it from the server's eviction;
+# the collector polls instead.  So the server keeps every finished answer
+# until it is taken: with the program's default of 4096, a stall of the
+# machine of a second or two at 4800 req/s puts the collector that far
+# behind, and the evicted answers would read as lost.
+RESULT_CAPACITY = 1 << 22
 POLL_S = 1e-3              # the longest the collector waits on one answer
 
 
@@ -174,7 +180,8 @@ class Outstanding:
 
 class _Served:
     """Shared by the server-driven mixes: the server with the program's
-    defaults, except what the configuration fixes."""
+    defaults, except what the configuration fixes and the benchmark's own
+    ``result_capacity`` (``RESULT_CAPACITY``)."""
 
     def __init__(self, system, config: dict, traffic: dict, seed: int,
                  span: Callable = no_span):
@@ -187,7 +194,8 @@ class _Served:
         self.plans.warmup()
         self.bucket_warmup_s = time.perf_counter() - t0
         self.server = SparseServer(self.plans, slo_ms=config["slo_ms"],
-                                   max_batch=config["max_batch"]).start()
+                                   max_batch=config["max_batch"],
+                                   result_capacity=RESULT_CAPACITY).start()
 
     def close(self) -> None:
         self.server.shutdown(drain=True)

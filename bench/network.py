@@ -2,21 +2,38 @@
 
 Weights come from one jitted call on the device.  The dense matrices stay
 with the benchmark: the program prunes its own copy through its public
-``prune_dense_stack``, and the reference (``configs/sparse_ffn_ref.py``)
+entries, and the reference (the configuration's ``"reference"`` module)
 prunes the same matrices by its own code, so it takes nothing the
 program made.
+
+A configuration whose ``"network"`` key names a module
+(``configs/<module>.py``) brings its own network through these hooks:
+
+  * ``make_dense(config, seed) -> (ws, bs)``: the dense f32 weights and
+    biases, which the reference's ``prune`` and ``forward`` also receive;
+  * ``prune(config, ws, bs) -> layers``: the program's pruning;
+  * ``compile(config, layers) -> BucketedPlanSet``: the program's plan;
+  * ``counts(config, nnz)``: the operations and bytes of one kernel call
+    (``flops``, ``bytes``, ``flops_per_row``, ``bound_s``, ``bound_by``, as
+    ``counts.SparseFFN`` has them), ``nnz`` the reference's kept blocks.
+
+The module defines all four.  A configuration without the key gets the
+chain's below: hidden -> intermediate -> hidden.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import List, Tuple
+from pathlib import Path
+from typing import Callable, Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.counts import SparseFFN
+from bench.spec import CONFIGS_DIR, load_module
 from repro.engine import Engine
 from repro.serving import BucketedPlanSet
 from repro.sparse import prune_dense_stack
@@ -36,9 +53,15 @@ def seed_key(seed: int) -> jax.Array:
 
 def make_dense(config: dict, seed: int
                ) -> Tuple[List[np.ndarray], List[np.ndarray]]:
-    """Dense f32 weights N(0, weight_std^2) and biases N(0, bias_std^2)
-    (zeros where the configuration has no bias), made on the device."""
-    sizes = widths(config)
+    """The chain's dense weights, at ``widths(config)``."""
+    return dense_chain(widths(config), config, seed)
+
+
+def dense_chain(sizes: Sequence[int], config: dict, seed: int
+                ) -> Tuple[List[np.ndarray], List[np.ndarray]]:
+    """Dense f32 weights N(0, weight_std^2) of a chain through ``sizes``,
+    and biases N(0, bias_std^2) (zeros where the configuration has no
+    bias), made on the device."""
     w_std = config["weight_std"]
     b_std = config["bias_std"] if config["bias"] else 0.0
 
@@ -66,17 +89,52 @@ class System:
     engine_compile_s: float   # BucketedPlanSet.compile
 
 
-def build(config: dict, seed: int) -> System:
-    """Weights from ``seed``, pruned and compiled by the program."""
-    (ws, bs), weights_s = timed(make_dense, config, seed)
+def _prune(config: dict, ws, bs) -> list:
     block = config["block"]
-    layers, prune_s = timed(prune_dense_stack, ws, bs, config["density"],
-                            block, block)
+    return prune_dense_stack(ws, bs, config["density"], block, block)
+
+
+def _compile(config: dict, layers) -> BucketedPlanSet:
     engine = Engine(backend=config["backend"],
                     activation=config["activation"],
                     weight_dtype=config["weight_dtype"])
-    plans = BucketedPlanSet.compile(layers, engine=engine,
-                                    max_batch=config["max_batch"])
+    return BucketedPlanSet.compile(layers, engine=engine,
+                                   max_batch=config["max_batch"])
+
+
+def _counts(config: dict, nnz: int) -> SparseFFN:
+    hidden = config["hidden_size"]
+    return SparseFFN(n_in=hidden, n_hid=config["intermediate_size"],
+                     n_out=hidden, block=config["block"], nnz=nnz,
+                     weight_dtype=config["weight_dtype"])
+
+
+CHAIN = {"make_dense": make_dense, "prune": _prune, "compile": _compile,
+         "counts": _counts}
+
+
+def hooks(config: dict, directory: Path = CONFIGS_DIR
+          ) -> Dict[str, Callable]:
+    """The configuration's hooks: its ``"network"`` module's, found in
+    ``directory``, else the chain's."""
+    name = config.get("network")
+    if name is None:
+        return dict(CHAIN)
+    module = load_module(name, directory)
+    missing = [hook for hook in CHAIN
+               if not callable(getattr(module, hook, None))]
+    if missing:
+        raise AttributeError(f"network module {name!r} defines no "
+                             f"{', '.join(missing)}")
+    return {hook: getattr(module, hook) for hook in CHAIN}
+
+
+def build(config: dict, seed: int, net: Dict[str, Callable]) -> System:
+    """Weights from ``seed``, pruned and compiled by the program through
+    the hooks ``net``."""
+    (ws, bs), weights_s = timed(net["make_dense"], config, seed)
+    layers, prune_s = timed(net["prune"], config, ws, bs)
+    plans = net["compile"](config, layers)
     return System(plans=plans, dense=(ws, bs), weights_s=weights_s,
                   prune_s=prune_s, engine_compile_s=plans.compile_s)
 

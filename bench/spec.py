@@ -5,6 +5,8 @@ and metric.  Each piece lives in a file of its own, found by its name:
 
   * a configuration: the file its entry names (``configs/<name>.json``);
   * a traffic mix: ``traffic/<name>.json``;
+  * a configuration's code: the modules its ``"reference"`` and, where it
+    has one, ``"network"`` keys name (``configs/<module>.py``);
   * a metric: ``metrics/<name>.py``, whose ``read(run)`` returns the
     number, or None when the run holds nothing to read.  A metric split by
     the end-to-end metric it moves (``device_idle.flood``) falls back to the
@@ -19,11 +21,14 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import json
+import sys
+import types
 from pathlib import Path
 from typing import Callable, Dict, List, Optional
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
+CONFIGS_DIR = BENCH_DIR / "configs"   # configurations' modules
 
 
 @dataclasses.dataclass
@@ -36,6 +41,7 @@ class Cell:
     traffic: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    modules: Path             # where its configuration's modules are
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -55,7 +61,8 @@ def load_traffic(name: str) -> dict:
     return traffic
 
 
-def load_cell(workload: str, root: Path = ROOT) -> Cell:
+def load_cell(workload: str, root: Path = ROOT,
+              modules: Path = CONFIGS_DIR) -> Cell:
     """The workload ``workload`` of ``BENCHMARK.json``; KeyError if absent."""
     bench = load_benchmark(root)
     entries = {w["name"]: w for w in bench["workloads"]}
@@ -73,20 +80,40 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
         traffic=load_traffic(entry["traffic"]),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, workload)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, workload)],
+        modules=modules,
     )
+
+
+def _exec_file(path: Path, prefix: str, name: str) -> types.ModuleType:
+    """The module in ``path``, loaded by path, since a name may hold dots
+    and dashes, and registered in ``sys.modules``, where a dataclass in it
+    looks its module up."""
+    module_name = f"{prefix}_{name.replace('.', '_').replace('-', '_')}"
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[module_name] = module
+    spec.loader.exec_module(module)
+    return module
+
+
+def load_module(name: str, directory: Path = CONFIGS_DIR
+                ) -> types.ModuleType:
+    """A configuration's module ``<directory>/<name>.py`` (its
+    ``"reference"`` or ``"network"``)."""
+    path = directory / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"no module {name!r} for the configuration: no {path}")
+    return _exec_file(path, "bench_config", name)
 
 
 def load_reader(metric: str) -> Callable[[object], Optional[float]]:
     """``metrics/<metric>.py``'s ``read``, else that of the name before the
-    first dot: loaded by path, since a metric's name may hold dots."""
+    first dot."""
     path = BENCH_DIR / "metrics" / f"{metric}.py"
     if not path.exists():
         path = BENCH_DIR / "metrics" / f"{metric.split('.')[0]}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return _exec_file(path, "bench_metric", metric).read
 
 
 def read_metrics(metrics: List[dict], run) -> Dict[str, dict]:

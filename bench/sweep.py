@@ -45,7 +45,8 @@ def main(argv=None) -> int:
     cell = load_cell(args.workload)
     slo_ms = cell.config["slo_ms"]
     p50, p99 = load_reader("latency_p50_ms"), load_reader("latency_p99_ms")
-    system = network.build(cell.config, args.seed)
+    system = network.build(cell.config, args.seed,
+                           network.hooks(cell.config, cell.modules))
     for rate in [float(r) for r in args.rates.split(",")]:
         traffic = {**cell.traffic, "rate_rps": rate}
         load = loadgen.OpenLoop(system, cell.config, traffic, args.seed)

@@ -59,7 +59,7 @@ class FakeServer:
     """FIFO server that answers a request once ``wait`` asks for it and
     counts how many are in flight at most."""
 
-    def __init__(self, plans, slo_ms, max_batch):
+    def __init__(self, plans, slo_ms, max_batch, result_capacity):
         self.plans, self.pending, self.peak = plans, {}, 0
         self.next = 0
         self.metrics = type("M", (), {})()
