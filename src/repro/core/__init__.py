@@ -12,6 +12,7 @@ from .compact_growth import CompactGrown, bandwidth, bandwidth_order, generate
 from .blocksparse import (
     BSRLayer,
     BlockFFNN,
+    GLUBlock,
     is_contiguous_by_output,
     regroup_by_output,
     schedule_arrays,
@@ -26,6 +27,6 @@ __all__ = [
     "Bounds", "theorem1_bounds",
     "ReorderResult", "connection_reordering", "propose",
     "CompactGrown", "bandwidth", "bandwidth_order", "generate",
-    "BSRLayer", "BlockFFNN", "is_contiguous_by_output", "schedule_arrays",
-    "simulated_tile_traffic", "to_block_ffnn", "to_bsr",
+    "BSRLayer", "BlockFFNN", "GLUBlock", "is_contiguous_by_output",
+    "schedule_arrays", "simulated_tile_traffic", "to_block_ffnn", "to_bsr",
 ]

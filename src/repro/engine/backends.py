@@ -292,18 +292,39 @@ def _flat_segments(layers, flat: FlatSchedule, activations):
     """Materialize per-layer views of the flat arrays once, outside any
     trace, so no per-call slicing of the big block array survives into the
     compiled program (shared by the fused jnp forward and its instrumented
-    measurement twin)."""
+    measurement twin).
+
+    A gated block's pair segment sums the up's steps into output tiles
+    ``[0, h)`` and the gate's into ``[h, 2h)``, ``h`` the hidden tiles, with
+    no activation: :func:`_glu_product` then makes the hidden state."""
     segs = []
     bias_row = 0
-    for k, (s, e) in enumerate(flat.segments):
-        lay = layers[k]
+    if flat.glu:
+        s, e = flat.segments[0]
+        gate = layers[0]
+        h = gate.grid_out
+        bias = jnp.concatenate([flat.bias_tiles[h:2 * h],
+                                flat.bias_tiles[:h]]).reshape(-1)
+        scales = None if flat.scales is None else flat.scales[s:e]
+        segs.append((flat.rows[s:e], flat.cols[s:e] + h * flat.operand[s:e],
+                     flat.blocks[s:e], scales, bias, gate.grid_in, 2 * h,
+                     None))
+        layers, activations = layers[2:], activations[2:]
+        bias_row = 2 * h
+    for lay, a, (s, e) in zip(layers, activations, flat.segments[len(segs):]):
         bias = flat.bias_tiles[bias_row:bias_row + lay.grid_out].reshape(-1)
         scales = None if flat.scales is None else flat.scales[s:e]
         segs.append((flat.rows[s:e], flat.cols[s:e], flat.blocks[s:e],
-                     scales, bias, lay.grid_in, lay.grid_out,
-                     activations[k]))
+                     scales, bias, lay.grid_in, lay.grid_out, a))
         bias_row += lay.grid_out
     return segs
+
+
+def _glu_product(h: jnp.ndarray, activation: Optional[Callable]):
+    """``[up | gate]`` pre-activations -> ``act(gate) * up``."""
+    n = h.shape[1] // 2
+    g = h[:, n:] if activation is None else activation(h[:, n:])
+    return g * h[:, :n]
 
 
 def make_fused_forward(
@@ -324,10 +345,15 @@ def make_fused_forward(
     (jnp) or grid step (megakernel) is predicated on its input tile holding
     any nonzero activation, skipping work that would contribute exactly
     zero — outputs stay bit-identical to the ungated forward.
+
+    A gated block (``flat.glu``; ``layers`` gate, up, down) runs as one
+    megakernel call, or as the pair segment and down on ``jnp``; its
+    ``activations[0]`` is the gate's.
     """
     layers = list(layers)
     activations = list(activations)
-    _check_fusible_activations(activations)
+    if not flat.glu:
+        _check_fusible_activations(activations)
     act = activations[0] if len(activations) > 1 else None
     fact = activations[-1]
 
@@ -337,10 +363,13 @@ def make_fused_forward(
 
         def forward_jnp(x):
             h = x
-            for rows, cols, blocks, scales, bias, gi, go, a in segs:
+            for k, (rows, cols, blocks, scales, bias, gi, go, a) in \
+                    enumerate(segs):
                 occ = tile_occupancy(h, bs, gi) if gate else None
                 h = _jnp_segment(h, rows, cols, blocks, bias,
                                  bs, bs, gi, go, a, occ=occ, scales=scales)
+                if flat.glu and k == 0:
+                    h = _glu_product(h, act)
             return h
 
         return jax.jit(forward_jnp) if jit else forward_jnp
@@ -360,6 +389,7 @@ def make_fused_forward(
             interpret=(backend == "interpret"),
         )
         kw["scales"] = flat.scales
+        kw["operand"] = flat.operand
         args = (xp, flat.blocks, flat.rows, flat.cols, flat.first,
                 flat.last, flat.layer_id, flat.hbm_row, flat.out_tile,
                 flat.bias_idx, flat.bias_tiles)

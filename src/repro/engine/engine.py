@@ -31,6 +31,7 @@ import numpy as np
 from repro.core.blocksparse import (
     BlockFFNN,
     BSRLayer,
+    GLUBlock,
     regroup_by_output,
     schedule_arrays,
     to_block_ffnn,
@@ -42,6 +43,7 @@ from repro.core.reorder import connection_reordering
 from repro.kernels.ops import (
     compile_flat_schedule,
     compile_schedule,
+    pack_attrs,
     resolve_weight_dtype,
 )
 from repro.models.common import ACTIVATIONS as _MODEL_ACTIVATIONS
@@ -91,7 +93,8 @@ class Engine:
         lowering elsewhere, so the same engine code runs (and is testable)
         on any machine.
       activation: epilogue fused into every layer but the last (name or
-        callable or None).  A list/tuple gives each *hidden* layer its own
+        callable or None); of a gated block (``GLUBlock``), the gate's.  A
+        list/tuple gives each *hidden* layer of a chain its own
         epilogue (length must be ``len(layers) - 1``); the megakernel fuses
         only when all hidden epilogues compare equal (``functools.partial``
         instances are compared structurally), otherwise the plan falls back
@@ -163,7 +166,7 @@ class Engine:
     # ------------------------------------------------------------------ #
     def compile(
         self,
-        net: Union[BlockFFNN, Sequence[BSRLayer]],
+        net: Union[BlockFFNN, GLUBlock, Sequence[BSRLayer]],
         backend: Optional[str] = None,
         mesh: Optional[Mesh] = None,
     ) -> Union[ExecutionPlan, ShardedExecutionPlan]:
@@ -177,7 +180,7 @@ class Engine:
         (Theorem-1 order + independent Connection Reordering per shard),
         and ``Mesh(1, 1)`` shares the unsharded plan's forward outright.
         """
-        bffnn = net if isinstance(net, BlockFFNN) else to_block_ffnn(list(net))
+        bffnn = net if isinstance(net, BlockFFNN) else to_block_ffnn(net)
         backend = resolve_backend(backend or self.backend)
         key = self._plan_key(bffnn, backend) + self._mesh_key(mesh)
         plan = self._cache.get(key)
@@ -192,7 +195,7 @@ class Engine:
 
     def compile_with_order(
         self,
-        net: Union[BlockFFNN, Sequence[BSRLayer]],
+        net: Union[BlockFFNN, GLUBlock, Sequence[BSRLayer]],
         order: np.ndarray,
         backend: Optional[str] = None,
         io: Optional[IOReport] = None,
@@ -207,7 +210,7 @@ class Engine:
         I/O re-simulation.  The rebuild is deterministic, so the resulting
         plan is bit-identical to the cold compile the order came from.
         """
-        bffnn = net if isinstance(net, BlockFFNN) else to_block_ffnn(list(net))
+        bffnn = net if isinstance(net, BlockFFNN) else to_block_ffnn(net)
         backend = resolve_backend(backend or self.backend)
         return self._build(bffnn, backend, order=np.asarray(order), io=io)
 
@@ -223,7 +226,7 @@ class Engine:
         sharded plan from one *stored* per-shard connection order each —
         zero annealer iterations, deterministic, bit-identical to the cold
         compile the orders came from (the plan store's warm path)."""
-        bffnn = net if isinstance(net, BlockFFNN) else to_block_ffnn(list(net))
+        bffnn = net if isinstance(net, BlockFFNN) else to_block_ffnn(net)
         backend = resolve_backend(backend or self.backend)
         return build_sharded_plan(self, bffnn, backend, mesh,
                                   orders=list(orders), ios=ios)
@@ -258,7 +261,7 @@ class Engine:
             self._act_key(self.final_activation),
             self.reorder, self.M_tiles, self.reorder_iters, self.seed,
             self.max_move_span, self.policy, self.fuse, self.gate,
-            resolve_weight_dtype(self.weight_dtype), self.jit,
+            resolve_weight_dtype(self.weight_dtype), self.jit, bffnn.glu,
         )
 
     # ------------------------------------------------------------------ #
@@ -271,18 +274,28 @@ class Engine:
         # resolve up front: an unavailable fp8 fails here with a clear
         # ValueError, never a deep kernel TypeError
         wdt = resolve_weight_dtype(self.weight_dtype)
+        glu = bffnn.glu
+        if glu:
+            self._check_glu()
         annealer_iters = 0
         if order is None:
             order = self.schedule_order(bffnn)
             annealer_iters = self.reorder_iters if self.reorder else 0
-        with tr.span("compile.pack", layers=len(layers)):
+        with tr.span("compile.pack", layers=len(layers)) as sp:
             schedules = []
             for k in range(len(layers)):
-                perm, _, _, _, _ = schedule_arrays(bffnn, order, k)
+                # a gated block's up steps pair with its gate's, whatever
+                # order Connection Reordering left them in
+                perm, _, _, _, _ = schedule_arrays(bffnn, order,
+                                                   0 if glu and k == 1 else k)
                 schedules.append(compile_schedule(layers[k], perm,
                                                   weight_dtype=wdt))
+            for key, value in pack_attrs(schedules, glu).items():
+                sp[key] = value
 
-        if isinstance(self.activation, (list, tuple)):
+        if glu:   # the gate's activation; the up projection has none
+            hidden = [_resolve_activation(self.activation), None]
+        elif isinstance(self.activation, (list, tuple)):
             if len(self.activation) != len(layers) - 1:
                 raise ValueError(
                     f"per-layer activation sequence has {len(self.activation)} "
@@ -300,8 +313,11 @@ class Engine:
             fallback_reason: Optional[str] = None
             if self.fuse:
                 try:
-                    flat = compile_flat_schedule(layers, schedules)
+                    flat = compile_flat_schedule(layers, schedules, glu=glu)
                 except ValueError as e:
+                    if glu:
+                        raise ValueError(f"a gated (GLU) block runs only "
+                                         f"fused, and {e}") from None
                     flat = None  # non-uniform tiles: per-layer fallback
                     fallback_reason = str(e)
             measure = None
@@ -318,6 +334,8 @@ class Engine:
                     # e.g. heterogeneous hidden epilogues: the megakernel
                     # fuses exactly one — record why instead of failing
                     # silently.
+                    if glu:
+                        raise
                     flat = None
                     fallback_reason = str(e)
             if flat is None:
@@ -350,6 +368,19 @@ class Engine:
             compile_s=time.perf_counter() - t0,
             annealer_iters=annealer_iters,
         )
+
+    def _check_glu(self) -> None:
+        """A gated (GLU) block runs only on the fused paths: refuse, by
+        name, what would run something else."""
+        if not self.fuse:
+            raise ValueError("a gated (GLU) block has no layered per-layer "
+                             "path; compile it with fuse=True")
+        if self.gate:
+            raise ValueError("occupancy gating (gate=True) does not handle "
+                             "a gated (GLU) block")
+        if isinstance(self.activation, (list, tuple)):
+            raise ValueError("a gated (GLU) block takes one activation, "
+                             "the gate's")
 
     def schedule_order(self, bffnn: BlockFFNN) -> np.ndarray:
         """Whole-DAG connection order: Theorem-1 grouping, then optional CR

@@ -459,6 +459,9 @@ class ExecutionPlan:
     def describe(self) -> str:
         shapes = " -> ".join(
             [str(self.n_in)] + [str(l.n_out) for l in self.layers])
+        if self.block_ffnn.glu:
+            shapes = (f"{self.n_in} -> 2 x {self.layers[0].n_out} (GLU) -> "
+                      f"{self.n_out}")
         nnz = sum(l.nnz_blocks for l in self.layers)
         mode = "fused" if self.fused else "layered"
         if self.gate:

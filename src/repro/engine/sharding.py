@@ -511,6 +511,8 @@ def build_sharded_plan(
     order (and optionally I/O report) per shard, skipping the annealing and
     re-simulation exactly like ``Engine.compile_with_order`` does.
     """
+    if bffnn.glu:
+        raise ValueError("sharded plans do not handle a gated (GLU) block")
     t0 = time.perf_counter()
     gate = bool(getattr(engine, "gate", False))
     specs = partition_model(bffnn, mesh.model)

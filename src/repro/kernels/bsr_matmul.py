@@ -141,7 +141,8 @@ def bsr_matmul(
 # --------------------------------------------------------------------------- #
 
 def _megakernel(
-    # scalar prefetch (``occ0_ref`` is appended when gating is on)
+    # scalar prefetch (``occ0_ref`` is appended when gating is on,
+    # ``op_ref`` for a gated unit)
     layer_ref, rows_ref, cols_ref, first_ref, last_ref,
     hbm_row_ref, out_tile_ref, bias_idx_ref,
     # inputs / outputs / scratch (layout depends on ``gate``; see below)
@@ -152,6 +153,7 @@ def _megakernel(
     gate: bool,
     quant: bool,
     valid_b: int,
+    glu: bool = False,
 ):
     """One grid step per nonzero block of ANY layer, in whole-net Theorem-1
     order.  The hidden state ping-pongs between two VMEM buffers across layer
@@ -183,17 +185,26 @@ def _megakernel(
     scale as one SMEM-resident vector, read at ``s_ref[g]``; dequant
     (``astype(f32) * scale``) is fused right before the dot, so only the
     VMEM-resident copy is ever widened — HBM traffic stays at the narrow
-    width."""
-    if gate and quant:
-        (occ0_ref, x_ref, w_ref, b_ref, s_ref, o_ref, occ_ref,
-         acc_ref, h0_ref, h1_ref) = rest
-    elif gate:
-        (occ0_ref, x_ref, w_ref, b_ref, o_ref, occ_ref,
-         acc_ref, h0_ref, h1_ref) = rest
-    elif quant:
-        x_ref, w_ref, b_ref, s_ref, o_ref, acc_ref, h0_ref, h1_ref = rest
+    width.
+
+    With ``glu=True`` layer 0 is a gated unit's gate and up, interleaved
+    pair by pair (``kernels.ops.FlatSchedule``), and layer 1 its down
+    projection.  The ``op_ref`` scalar-prefetch array sends a gate step's
+    product to a second accumulator, ``accg_ref``; both start on the gate's
+    first step of a hidden tile.  The gate's last step of the tile turns
+    ``accg_ref`` into ``activation(acc_g + b_g)`` in place, and the up's
+    last step, the next one, writes ``act(acc_g + b_g) * (acc_u + b_u)``
+    into the hidden buffer, which down reads as any hidden layer is read."""
+    rest = list(rest)
+    occ0_ref = rest.pop(0) if gate else None
+    op_ref = rest.pop(0) if glu else None
+    accg_ref = rest.pop() if glu else None
+    x_ref, w_ref, b_ref, *rest = rest
+    s_ref = rest.pop(0) if quant else None
+    if gate:
+        o_ref, occ_ref, acc_ref, h0_ref, h1_ref = rest
     else:
-        x_ref, w_ref, b_ref, o_ref, acc_ref, h0_ref, h1_ref = rest
+        o_ref, acc_ref, h0_ref, h1_ref = rest
     g = pl.program_id(0)
     lid = layer_ref[g]
     r = rows_ref[g]
@@ -204,6 +215,8 @@ def _megakernel(
     @pl.when(first_ref[g] == 1)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        if glu:
+            accg_ref[...] = jnp.zeros_like(accg_ref)
 
     if gate:
         # occupancy of this step's input tile (clamped reads: the occ0 /
@@ -218,6 +231,17 @@ def _megakernel(
 
     # multiply-accumulate from this step's input tile (skipped when gating
     # proves the tile dead — the contribution would be exactly zero)
+    if glu:
+        to_gate = op_ref[g] == 1
+
+        @pl.when(to_gate)          # gate steps are all in layer 0
+        def _gate_from_hbm():
+            accg_ref[...] += jnp.dot(
+                x_ref[...], w, preferred_element_type=jnp.float32
+            )
+
+        alive = ~to_gate
+
     @pl.when((lid == 0) & alive)
     def _from_hbm():
         acc_ref[...] += jnp.dot(
@@ -249,10 +273,23 @@ def _megakernel(
 
     if n_layers > 1:
         c = cols_ref[g]
+        if glu:
+            @pl.when((last_ref[g] == 1) & to_gate)
+            def _gate_epilogue():
+                y = accg_ref[...] + b_ref[0].astype(jnp.float32)
+                if activation is not None:
+                    y = activation(y)
+                accg_ref[...] = y
+
+        def _stash_at(parity):
+            at = (last_ref[g] == 1) & ~is_final & (lid % 2 == parity)
+            return at & ~to_gate if glu else at
 
         def _stash(h_ref):
             y = acc_ref[...] + b_ref[0].astype(jnp.float32)
-            if activation is not None:
+            if glu:
+                y = accg_ref[...] * y
+            elif activation is not None:
                 y = activation(y)
             h_ref[c] = y
             if gate:
@@ -261,11 +298,11 @@ def _megakernel(
                                axis=1, keepdims=True)
                 occ_ref[lid, c] = jnp.sum(live.astype(jnp.int32))
 
-        @pl.when((last_ref[g] == 1) & ~is_final & (lid % 2 == 0))
+        @pl.when(_stash_at(0))
         def _stash_h0():
             _stash(h0_ref)
 
-        @pl.when((last_ref[g] == 1) & ~is_final & (lid % 2 == 1))
+        @pl.when(_stash_at(1))
         def _stash_h1():
             _stash(h1_ref)
 
@@ -290,6 +327,7 @@ def bsr_megakernel(
     bias_tiles: jnp.ndarray,  # [total_out_tiles, bs]
     occ0: Optional[jnp.ndarray] = None,  # int32 [grid_in_0] (gate only)
     scales: Optional[jnp.ndarray] = None,  # f32 [nnz_total] dequant (quant)
+    operand: Optional[jnp.ndarray] = None,  # int32 [nnz_total] (GLU only)
     n_layers: int = 1,
     block: int = 0,
     grid_out_final: int = 0,
@@ -313,6 +351,11 @@ def bsr_megakernel(
     of hidden activation ``k``'s tile ``t`` — the very counts the gating
     predicates consumed, exported so dynamic I/O is measurable (and the
     padded-row exclusion testable) from outside the kernel.
+
+    ``operand`` (1 on a gate step) makes the call a gated unit's: two layers,
+    the gate's and up's steps paired, then down (see ``_megakernel``).  Its
+    presence is a static flag, as that of ``scales`` is; without it the
+    kernel lowers as a chain's.
     """
     B, n_in = x.shape
     nnz = blocks.shape[0]
@@ -321,6 +364,10 @@ def bsr_megakernel(
     if n_in % bs:
         raise ValueError("n_in must be a multiple of the block size")
     quant = scales is not None
+    glu = operand is not None
+    if glu and (gate or n_layers != 2):
+        raise ValueError("the megakernel runs a gated (GLU) block as two "
+                         "layers without occupancy gating")
 
     in_specs = [
         # input tile: only layer-0 steps move this index; afterwards it
@@ -342,7 +389,7 @@ def bsr_megakernel(
     # index maps take (g, *scalar_prefetch); variadic so the same lambdas
     # serve both the 8-array and the gated 9-array prefetch layout
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=9 if gate else 8,
+        num_scalar_prefetch=9 if gate or glu else 8,
         grid=(nnz,),
         in_specs=in_specs,
         out_specs=(
@@ -355,7 +402,7 @@ def bsr_megakernel(
             pltpu.VMEM((B, bs), jnp.float32),                  # accumulator
             pltpu.VMEM((hidden_tiles, B, bs), jnp.float32),    # hidden ping
             pltpu.VMEM((hidden_tiles, B, bs), jnp.float32),    # hidden pong
-        ],
+        ] + ([pltpu.VMEM((B, bs), jnp.float32)] if glu else []),  # gate's
     )
     out_shape = jax.ShapeDtypeStruct((B, n_out), x.dtype)
     if gate:
@@ -371,6 +418,7 @@ def bsr_megakernel(
             gate=gate,
             quant=quant,
             valid_b=valid_b,
+            glu=glu,
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
@@ -383,6 +431,8 @@ def bsr_megakernel(
                 bias_idx)
     if gate:
         prefetch += (occ0,)
+    if glu:
+        prefetch += (operand,)
     args = (x, blocks, bias_tiles.reshape(bias_tiles.shape[0], 1, bs))
     if quant:
         args += (scales,)

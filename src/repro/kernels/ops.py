@@ -125,6 +125,9 @@ class CompiledSchedule:
     # ``scales`` holds one f32 dequant factor per block (None for f32)
     scales: Optional[jnp.ndarray] = None
     weight_dtype: str = "f32"
+    # trailing steps that carry a zero block for an output tile no kept
+    # block reaches (bias + activation still have to run there)
+    patched: int = 0
 
     @property
     def weight_bytes(self) -> int:
@@ -188,7 +191,16 @@ def compile_schedule(
         sim_writes=sim_writes,
         scales=None if scales is None else jnp.asarray(scales),
         weight_dtype=resolve_weight_dtype(weight_dtype),
+        patched=int(len(missing)),
     )
+
+
+def pack_attrs(schedules: Sequence[CompiledSchedule], glu: bool) -> dict:
+    """What packing made of a network's schedules: ``glu_pairs``, the
+    gate/up step pairs of a gated block (0 for a chain), and
+    ``patched_steps``, the steps that carry a zero patch block."""
+    return {"glu_pairs": int(schedules[0].rows.shape[0]) if glu else 0,
+            "patched_steps": sum(sch.patched for sch in schedules)}
 
 
 @dataclasses.dataclass
@@ -213,6 +225,14 @@ class FlatSchedule:
     ``segments[k] = (start, end)`` delimits layer ``k``'s steps; the ``jnp``
     lowering consumes exactly these flat arrays one segment at a time, so
     all backends execute the identical connection order.
+
+    A gated block (``operand`` not None) has two segments: the gate's and
+    the up's steps interleaved pair by pair, then down.  ``operand[g]`` is 1
+    on a gate step and 0 on every other; ``first`` marks only the gate's
+    first step of a hidden tile (both accumulators start there) and
+    ``last`` the gate's and the up's last steps of it.  ``bias_idx`` points
+    at the gate's bias tile on every step of the pair segment but the up's
+    last of each tile, so the bias tile moves twice a tile, not every step.
     """
 
     blocks: jnp.ndarray       # [nnz_total, bs, bs] scheduled order
@@ -238,10 +258,18 @@ class FlatSchedule:
     # f32 dequant factor per flat step (None for f32)
     scales: Optional[jnp.ndarray] = None
     weight_dtype: str = "f32"
+    # gated block: int32 [nnz_total], 1 on a gate step (None for a chain)
+    operand: Optional[jnp.ndarray] = None
+    glu_pairs: int = 0        # gate/up step pairs (0 for a chain)
+    patched_steps: int = 0    # steps that carry a zero patch block
 
     @property
     def nnz(self) -> int:
         return int(self.rows.shape[0])
+
+    @property
+    def glu(self) -> bool:
+        return self.operand is not None
 
     @property
     def weight_bytes(self) -> int:
@@ -261,9 +289,16 @@ class FlatSchedule:
         return sum(w for _, w in self.per_layer_io)
 
 
+def _pairs(a, b):
+    """``[a0, b0, a1, b1, ...]`` of two equally long arrays."""
+    stack = jnp.stack if isinstance(a, jnp.ndarray) else np.stack
+    return stack([a, b], axis=1).reshape((-1,) + tuple(a.shape[1:]))
+
+
 def compile_flat_schedule(
     layers: Sequence[BSRLayer],
     schedules: Sequence[CompiledSchedule],
+    glu: bool = False,
 ) -> FlatSchedule:
     """Concatenate per-layer schedules into one megakernel-ready flat schedule.
 
@@ -271,6 +306,10 @@ def compile_flat_schedule(
     ``block_n`` (and square tiles when depth > 1, since layer k's output
     tiles are layer k+1's input tiles).  Raises ``ValueError`` otherwise —
     the engine falls back to per-layer dispatch in that case.
+
+    ``glu=True``: ``layers`` are a gated block's ``[gate, up, down]``; the
+    gate's and the up's steps are interleaved into one segment (see
+    :class:`FlatSchedule`), so the grid walks two layers.
     """
     if not layers or len(layers) != len(schedules):
         raise ValueError("need one schedule per layer")
@@ -281,30 +320,49 @@ def compile_flat_schedule(
                 "flat schedule requires one uniform square tile size across "
                 f"layers; got ({lay.block_m}, {lay.block_n}) vs {bs}"
             )
+    wdt = schedules[0].weight_dtype
+    for sch in schedules:
+        if sch.weight_dtype != wdt:
+            raise ValueError(
+                "flat schedule requires one weight_dtype across layers; got "
+                f"{sch.weight_dtype!r} vs {wdt!r}"
+            )
 
-    rows_l: List[np.ndarray] = []
-    cols_l: List[np.ndarray] = []
-    first_l: List[np.ndarray] = []
-    last_l: List[np.ndarray] = []
-    lid_l: List[np.ndarray] = []
+    bias_off = np.cumsum([0] + [lay.grid_out for lay in layers])
+    # one entry per segment: rows, cols, first, last, bias_idx, blocks,
+    # scales, operand
+    parts = []
+    chain = list(enumerate(schedules))
+    if glu:   # gate and up share one mask and one order (GLUBlock)
+        g, u = schedules[0], schedules[1]
+        rows, cols = np.asarray(g.rows), np.asarray(g.cols)
+        last_u = np.asarray(u.last)
+        parts.append((
+            _pairs(rows, rows), _pairs(cols, cols),
+            _pairs(np.asarray(g.first), np.zeros_like(last_u)),
+            _pairs(np.asarray(g.last), last_u),
+            _pairs(cols, np.where(last_u == 1, bias_off[1] + cols, cols)),
+            _pairs(g.blocks, u.blocks),
+            None if g.scales is None else _pairs(g.scales, u.scales),
+            _pairs(np.ones_like(rows), np.zeros_like(rows))))
+        chain = chain[2:]
+    for k, sch in chain:
+        cols = np.asarray(sch.cols, dtype=np.int32)
+        parts.append((
+            np.asarray(sch.rows), cols, np.asarray(sch.first),
+            np.asarray(sch.last), bias_off[k] + cols, sch.blocks, sch.scales,
+            np.zeros_like(cols) if glu else None))
+
+    rows, cols, first, last, bias_idx = (
+        np.concatenate(arrs).astype(np.int32) for arrs in zip(*[
+            p[:5] for p in parts]))
     segments: List[Tuple[int, int]] = []
-    per_layer_io: List[Tuple[int, int]] = []
     off = 0
-    for k, sch in enumerate(schedules):
-        n = int(sch.rows.shape[0])
-        rows_l.append(np.asarray(sch.rows, dtype=np.int32))
-        cols_l.append(np.asarray(sch.cols, dtype=np.int32))
-        first_l.append(np.asarray(sch.first, dtype=np.int32))
-        last_l.append(np.asarray(sch.last, dtype=np.int32))
-        lid_l.append(np.full(n, k, dtype=np.int32))
-        segments.append((off, off + n))
-        per_layer_io.append((sch.sim_reads, sch.sim_writes))
-        off += n
-    rows = np.concatenate(rows_l)
-    cols = np.concatenate(cols_l)
-    first = np.concatenate(first_l)
-    last = np.concatenate(last_l)
-    layer_id = np.concatenate(lid_l)
+    for p in parts:
+        segments.append((off, off + len(p[0])))
+        off += len(p[0])
+    layer_id = np.concatenate([np.full(e - s, k, dtype=np.int32)
+                               for k, (s, e) in enumerate(segments)])
 
     # hbm_row: live during layer 0, frozen afterwards (constant index map
     # result => Pallas keeps the current block in VMEM, no extra fetch)
@@ -318,28 +376,15 @@ def compile_flat_schedule(
     fs, fe = segments[-1]
     out_tile = np.full(off, int(cols[fs]), dtype=np.int32)
     out_tile[fs:fe] = cols[fs:fe]
-    # flat bias tiles + per-step bias row
-    bias_off = np.zeros(len(layers) + 1, dtype=np.int64)
-    for k, lay in enumerate(layers):
-        bias_off[k + 1] = bias_off[k] + lay.grid_out
-    bias_idx = (bias_off[layer_id] + cols).astype(np.int32)
+    # flat bias tiles; ``bias_idx`` holds each step's row of them
     bias_tiles = np.concatenate(
         [np.asarray(lay.bias, dtype=np.float32).reshape(lay.grid_out, -1)
          for lay in layers])
 
-    wdt = schedules[0].weight_dtype
-    for sch in schedules:
-        if sch.weight_dtype != wdt:
-            raise ValueError(
-                "flat schedule requires one weight_dtype across layers; got "
-                f"{sch.weight_dtype!r} vs {wdt!r}"
-            )
-    scales = None if wdt == "f32" else \
-        jnp.concatenate([sch.scales for sch in schedules])
-
+    scales = None if wdt == "f32" else jnp.concatenate([p[6] for p in parts])
     hidden_tiles = max([lay.grid_out for lay in layers[:-1]] or [1])
     return FlatSchedule(
-        blocks=jnp.concatenate([sch.blocks for sch in schedules]),
+        blocks=jnp.concatenate([p[5] for p in parts]),
         rows=jnp.asarray(rows),
         cols=jnp.asarray(cols),
         first=jnp.asarray(first),
@@ -350,14 +395,18 @@ def compile_flat_schedule(
         bias_idx=jnp.asarray(bias_idx),
         bias_tiles=jnp.asarray(bias_tiles),
         segments=tuple(segments),
-        n_layers=len(layers),
+        n_layers=len(parts),
         block=bs,
         grid_out_final=layers[-1].grid_out,
         n_out=layers[-1].n_out,
         hidden_tiles=int(hidden_tiles),
-        per_layer_io=tuple(per_layer_io),
+        per_layer_io=tuple((sch.sim_reads, sch.sim_writes)
+                           for sch in schedules),
         scales=scales,
         weight_dtype=wdt,
+        operand=jnp.asarray(np.concatenate([p[7] for p in parts]).astype(
+            np.int32)) if glu else None,
+        **pack_attrs(schedules, glu),
     )
 
 

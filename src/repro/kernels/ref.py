@@ -41,6 +41,27 @@ def bsr_matmul_ref(
     return y.astype(x.dtype)
 
 
+def glu_ffn_ref(
+    x: jnp.ndarray,
+    weights,
+    biases,
+    activation: Optional[Callable],
+) -> jnp.ndarray:
+    """Oracle of a gated unit: ``(act(x Wg + bg) * (x Wu + bu)) Wd + bd``.
+
+    ``weights`` are the dense ``(Wg, Wu, Wd)``, ``biases`` ``(bg, bu, bd)``;
+    plain float32 ``jax.numpy`` under the highest matmul precision, with no
+    kernel, schedule or batching."""
+    wg, wu, wd = (jnp.asarray(w, jnp.float32) for w in weights)
+    bg, bu, bd = (jnp.asarray(b, jnp.float32) for b in biases)
+    x = jnp.asarray(x, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        g = x @ wg + bg
+        if activation is not None:
+            g = activation(g)
+        return (g * (x @ wu + bu)) @ wd + bd
+
+
 def moe_gemm_ref(
     x: jnp.ndarray,          # [tokens, d]
     w_up: jnp.ndarray,       # [experts, d, f]

@@ -17,6 +17,8 @@ from __future__ import annotations
 import threading
 from typing import Dict, Optional
 
+from repro.kernels.ops import pack_attrs
+
 __all__ = ["plan_io_attrs", "IOTelemetry"]
 
 #: occupancy-histogram bin labels, matching ``DynamicIOReport.per_layer_hist``
@@ -103,6 +105,10 @@ def plan_io_attrs(plan) -> Dict[str, object]:
     nnz = _nnz_blocks(plan)
     if nnz:
         attrs["nnz_blocks"] = nnz
+    schedules = getattr(plan, "schedules", None)
+    if schedules:
+        glu = getattr(getattr(plan, "block_ffnn", None), "glu", False)
+        attrs.update(pack_attrs(schedules, glu))
     return attrs
 
 

@@ -24,8 +24,9 @@ Design constraints, in order:
     ``NULL_TRACER`` included, is also written into the profiler's trace as
     a ``TraceAnnotation`` (the ``/host:CPU`` plane, on the device trace's
     clock), and its duration is added to a process-wide per-name total
-    (:func:`span_totals`).  The session is process-wide, and so are the
-    totals.
+    (:func:`span_totals`); :func:`count` adds to a process-wide counter
+    (:func:`counter_totals`) in the same sessions only.  The session is
+    process-wide, and so are the totals.
 
 Span taxonomy (names, attributes, units) is documented in
 ``docs/observability.md``.
@@ -44,7 +45,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 from jax.profiler import TraceAnnotation
 
 __all__ = ["Span", "Tracer", "NULL_TRACER", "span_totals",
-           "reset_span_totals"]
+           "reset_span_totals", "count", "counter_totals"]
 
 #: True while a profiler session records host events
 _profiling = TraceAnnotation.is_enabled
@@ -53,6 +54,7 @@ _PROFILER_ARGS = ("bucket", "rows")
 
 _totals_mu = threading.Lock()
 _totals: Dict[str, List] = {}           # name -> [count, seconds]
+_counters: Dict[str, int] = {}          # name -> total
 
 
 def _add_total(name: str, seconds: float) -> None:
@@ -75,8 +77,26 @@ def span_totals() -> Dict[str, Tuple[int, float]]:
 
 
 def reset_span_totals() -> None:
+    """Start the span totals and the counters afresh."""
     with _totals_mu:
         _totals.clear()
+        _counters.clear()
+
+
+def count(name: str, n: int) -> None:
+    """Add ``n`` to the process-wide counter ``name`` while a profiler
+    session records; otherwise do nothing (one check of the session)."""
+    if not _profiling():
+        return
+    with _totals_mu:
+        _counters[name] = _counters.get(name, 0) + int(n)
+
+
+def counter_totals() -> Dict[str, int]:
+    """``{name: total}`` of what :func:`count` added while a profiler
+    session was recording (since the last :func:`reset_span_totals`)."""
+    with _totals_mu:
+        return dict(_counters)
 
 
 @dataclasses.dataclass

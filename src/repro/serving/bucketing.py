@@ -30,7 +30,7 @@ import numpy as np
 
 from repro.core.blocksparse import BlockFFNN, BSRLayer
 from repro.engine import Engine, ExecutionPlan, Mesh, ShardedExecutionPlan
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import NULL_TRACER, count
 
 AnyPlan = Union[ExecutionPlan, ShardedExecutionPlan]
 
@@ -234,8 +234,20 @@ class BucketedPlanSet:
                     [x, np.zeros((b - n, x.shape[1]), x.dtype)])
             self.bucket_calls[b] += 1
             y = self.plans[b](x)
+            self._count_steps()
         with tr.span("plans.fetch", bucket=b, rows=n):
             return np.asarray(y)[:n]
+
+    def _count_steps(self) -> None:
+        """Add one megakernel call's grid steps to the ``megakernel.steps``
+        counter, and its gate/up steps to ``megakernel.glu_steps``; both
+        count only while a profiler session records."""
+        flat = getattr(self.base, "flat", None)
+        if flat is None or self.base.backend == "jnp":
+            return
+        count("megakernel.steps", flat.nnz)
+        if flat.glu:
+            count("megakernel.glu_steps", 2 * flat.glu_pairs)
 
     def describe(self) -> str:
         src = "plan-store hit" if self.cache_hit else "cold compile"

@@ -44,7 +44,7 @@ from repro.checkpoint.store import (
     read_manifest_dir,
     write_manifest_dir,
 )
-from repro.core.blocksparse import BlockFFNN, BSRLayer
+from repro.core.blocksparse import BlockFFNN, BSRLayer, GLUBlock
 from repro.kernels.ops import resolve_weight_dtype
 from repro.engine import (
     Engine,
@@ -59,6 +59,9 @@ FORMAT_VERSION = 1
 
 
 def _layers_of(net: Union[BlockFFNN, Sequence[BSRLayer]]):
+    if isinstance(net, GLUBlock) or getattr(net, "glu", False):
+        raise ValueError("the plan store does not handle a gated (GLU) "
+                         "block")
     return net.layers if isinstance(net, BlockFFNN) else list(net)
 
 

@@ -21,8 +21,11 @@ import numpy as np
 from repro.core.blocksparse import (
     BlockFFNN,
     BSRLayer,
+    GLUBlock,
     simulated_tile_traffic,
+    tile_mass,
     to_bsr,
+    top_tiles,
 )
 from repro.engine import Engine, ExecutionPlan
 from repro.kernels.ops import CompiledSchedule
@@ -40,6 +43,33 @@ def prune_dense_stack(
         to_bsr(w, block_m, block_n, density=density, bias=b)
         for w, b in zip(weights, biases)
     ]
+
+
+def prune_glu(
+    weights: Sequence[np.ndarray],
+    biases: Sequence[np.ndarray],
+    density: float,
+    block: int = 128,
+) -> GLUBlock:
+    """Block-magnitude-prune a gated unit ``(Wg, Wu, Wd)`` to ``density``.
+
+    Gate and up keep one mask: the top ``density`` share of (input tile,
+    hidden tile) positions by their joint mass ``sqrt(|G_ij|^2 + |U_ij|^2)``.
+    A hidden unit of a GLU is the product of its gate and up columns, so
+    structured GLU pruning keeps or drops the pair together: a gate block
+    kept without its up block (or the reverse) would spend a weight on a
+    product the other factor zeroes.  Down is pruned by its own block mass.
+    """
+    wg, wu, wd = weights
+    bg, bu, bd = biases
+    joint = np.sqrt(tile_mass(wg, block, block) ** 2
+                    + tile_mass(wu, block, block) ** 2)
+    mask = top_tiles(joint, density)
+    return GLUBlock(
+        gate=to_bsr(wg, block, block, bias=bg, mask=mask),
+        up=to_bsr(wu, block, block, bias=bu, mask=mask),
+        down=to_bsr(wd, block, block, density=density, bias=bd),
+    )
 
 
 @dataclasses.dataclass

@@ -36,6 +36,8 @@ from repro.obs import (
     IOTelemetry,
     MetricsServer,
     Tracer,
+    count,
+    counter_totals,
     plan_io_attrs,
     render_prometheus,
     reset_span_totals,
@@ -229,6 +231,9 @@ def test_plan_io_attrs_static(make_stack):
         attrs["io_tile_reads"] + attrs["io_tile_writes"]
     assert attrs["nnz_blocks"] > 0
     assert isinstance(attrs["io_within_bounds"], bool)
+    # a chain has no gate/up pairs; packing patched what no block reaches
+    assert attrs["glu_pairs"] == 0
+    assert attrs["patched_steps"] == sum(s.patched for s in plan.schedules)
     # defensive on non-plan objects: empty dict, never a raise
     assert plan_io_attrs(object()) == {}
 
@@ -579,6 +584,56 @@ def test_ring_and_profiler_share_one_span(tmp_path):
     assert span_totals()["plans.dispatch"][0] == 1
     (prof,) = [e for e in events if e[0] == "plans.dispatch"]
     assert prof[3] == {"bucket": 2, "rows": 1}
+
+
+def test_counters_count_only_while_a_profiler_records(tmp_path):
+    reset_span_totals()
+    count("megakernel.steps", 5)
+    assert counter_totals() == {}
+    with _profiled(tmp_path):
+        count("megakernel.steps", 5)
+        count("megakernel.steps", 2)
+        count("megakernel.glu_steps", 4)
+    count("megakernel.steps", 100)          # the session has stopped
+    assert counter_totals() == {"megakernel.steps": 7,
+                                "megakernel.glu_steps": 4}
+    reset_span_totals()                     # resets with the span totals
+    assert counter_totals() == {}
+
+
+@pytest.mark.parametrize("glu", [False, True], ids=["chain", "glu"])
+def test_plan_set_counts_megakernel_grid_steps(make_stack, tmp_path, glu):
+    """Each kernel call's grid steps at dispatch, while a profiler records:
+    one offline call of three chunks; a gated block's pairs besides."""
+    if glu:
+        from repro.sparse import prune_glu
+        rng = np.random.default_rng(2)
+        net = prune_glu(
+            [0.1 * rng.standard_normal(s).astype(np.float32)
+             for s in ((128, 256), (128, 256), (256, 128))],
+            [np.zeros(n, np.float32) for n in (256, 256, 128)], 0.5, 128)
+    else:
+        net = make_stack()
+    plans = BucketedPlanSet.compile(
+        net, engine=Engine(backend="interpret", activation="silu"),
+        max_batch=8)
+    flat = plans.base.flat
+    x = np.ones((20, plans.n_in), np.float32)
+    plans(x)                                  # traced and run outside
+    with _profiled(tmp_path):
+        plans(x)
+    steps = counter_totals()
+    assert steps["megakernel.steps"] == 3 * flat.nnz
+    if glu:
+        assert flat.glu_pairs > 0
+        assert steps["megakernel.glu_steps"] == 3 * 2 * flat.glu_pairs
+    else:
+        assert "megakernel.glu_steps" not in steps
+    jnp_plans = BucketedPlanSet.compile(net, engine=Engine(
+        backend="jnp", activation="silu"), max_batch=8)
+    with _profiled(tmp_path / "jnp"):
+        jnp_plans(x)
+    assert counter_totals() == {}             # no megakernel call
 
 
 def test_no_session_leaves_spans_null_and_totals_still(make_stack):

@@ -5,8 +5,11 @@ topology that is described, not attached.  This catches what interpret mode
 cannot — block shapes the Mosaic lowering refuses and kernels that need more
 VMEM than the chip allows — at the BERT-large encoder FFN widths
 (1024 -> 4096 -> 1024, block 128, gelu) with the server's largest bucket
-(B = 32).  Nothing runs, so nothing here says anything about results or
-times.
+(B = 32), and the gated megakernel at Olmo-Hybrid-7B's MLP widths (3840 ->
+2 x 11008 -> 3840, silu, bf16) with its offline chunk (B = 128), whose
+hidden scratch (2 x 86 x 128 x 128 f32, 11.3 MB) is the largest any cell
+keeps in VMEM.  Nothing runs, so nothing here says anything about results
+or times.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -78,6 +81,30 @@ def _megakernel_case(sh, wdt, gate):
     return fn, args + [extra[k] for k in names]
 
 
+# Olmo-Hybrid-7B's gated MLP at density 0.1: 258 gate/up pairs and 258
+# down blocks, rounded up (patch steps); only the grid length depends on it
+GLU_IN, GLU_HID, GLU_B, GLU_NNZ = 3840, 11008, 128, 800
+
+
+def _glu_case(sh):
+    i32 = jnp.int32
+    kw = dict(n_layers=2, block=BS, grid_out_final=GLU_IN // BS,
+              hidden_tiles=GLU_HID // BS, activation=jax.nn.silu,
+              final_activation=None)
+    # x, blocks, the eight schedule arrays, bias tiles, scales, operand
+    args = [_spec((GLU_B, GLU_IN), jnp.float32, sh),
+            _spec((GLU_NNZ, BS, BS), BF16_DTYPE, sh),
+            *[_spec((GLU_NNZ,), i32, sh) for _ in range(8)],
+            _spec(((2 * GLU_HID + GLU_IN) // BS, BS), jnp.float32, sh),
+            _spec((GLU_NNZ,), jnp.float32, sh),
+            _spec((GLU_NNZ,), i32, sh)]
+
+    def fn(*a):
+        return bsr_megakernel(*a[:11], scales=a[11], operand=a[12], **kw)
+
+    return fn, args
+
+
 def _layered_case(sh, wdt):
     i32 = jnp.int32
     args = [_spec((B, N_IN), jnp.float32, sh),
@@ -99,6 +126,7 @@ CASES = {
     "megakernel-f32-gated": lambda sh: _megakernel_case(sh, jnp.float32, True),
     "megakernel-bf16": lambda sh: _megakernel_case(sh, BF16_DTYPE, False),
     "megakernel-fp8": lambda sh: _megakernel_case(sh, FP8_DTYPE, False),
+    "megakernel-glu-bf16": _glu_case,
     "layered-bf16": lambda sh: _layered_case(sh, BF16_DTYPE),
 }
 
